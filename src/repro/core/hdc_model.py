@@ -548,7 +548,6 @@ fit.__doc__ = "Single-pass training from scratch: reset, encode, bundle."
 def _sharded_partial_fit_fn(cfg: HDCConfig, mesh: Mesh, rules):
     """Build (and cache, keyed by config/mesh/rules) the jitted shard_map
     partial_fit step.  See `partial_fit_sharded` for the semantics."""
-    from jax.experimental.shard_map import shard_map
 
     from repro.distributed.sharding import model_axis_for
 
@@ -586,12 +585,12 @@ def _sharded_partial_fit_fn(cfg: HDCConfig, mesh: Mesh, rules):
             n_seen=_nseen_add(m.n_seen, labels.shape[0] * bsz),
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=(mspecs, P(bspec, None), P(bspec)),
         out_specs=mspecs,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn), bsz
 

@@ -28,6 +28,12 @@ accumulator.  The (B, D) hypervector batch therefore never exists in
 HBM, even tiled: the only HBM traffic of a training step is the
 quantized inputs, the label indicator, the encoder state (threshold
 tile or direction matrix) and the (C, D) class sums (DESIGN.md §9).
+
+TPU block rules shape every tile here: the last two dims of a block
+are multiples of (8, 128) or equal the array's own dims.  So the H
+tile is 128 lanes (callers pad H, e.g. 784 -> 896, and correct), and
+the fit kernels take the label indicator batch-major, (B, cp) blocked
+(bt, cp) with cp the whole padded class axis.
 """
 
 from __future__ import annotations
@@ -37,6 +43,33 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+
+def _sobol_tile(dirs, first, block_d: int, shift: int, n_bits: int):
+    """In-kernel (ht, dt) quantized Sobol tile for points [first,
+    first + block_d): point p = XOR of the direction columns selected
+    by the bits of gray(p), right-shifted by `shift`.  `first` may be a
+    runtime scalar."""
+    idx = jax.lax.broadcasted_iota(jnp.uint32, (1, block_d), 1) + jnp.asarray(
+        first
+    ).astype(jnp.uint32)
+    gray = idx ^ (idx >> jnp.uint32(1))
+    acc = jnp.zeros((dirs.shape[0], block_d), jnp.uint32)
+    for bit in range(n_bits):
+        mask = (gray >> jnp.uint32(bit)) & jnp.uint32(1)  # (1, dt)
+        acc = acc ^ (mask * dirs[:, bit : bit + 1])
+    return (acc >> jnp.uint32(shift)).astype(jnp.int32)
+
+
+def _bundle_into(o_ref, oh, hv):
+    """o (cp, dt) += oh^T @ hv in int32 on the VPU: oh (bt, cp) {0,1}
+    batch-major indicator, hv (bt, dt) hypervector slab.  One masked
+    sublane reduction per class row (exact; cp is small)."""
+    for c in range(oh.shape[1]):
+        o_ref[c : c + 1, :] += (oh[:, c : c + 1] * hv).sum(
+            axis=0, keepdims=True, dtype=jnp.int32
+        )
 
 
 def _encode_bundle_kernel(x_ref, s_ref, o_ref, *, ht: int):
@@ -57,7 +90,7 @@ def encode_bundle_pallas(
     sobol_q: jax.Array,
     *,
     block_b: int = 8,
-    block_h: int = 112,
+    block_h: int = 128,
     block_d: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
@@ -105,14 +138,7 @@ def _encode_bundle_dyn_kernel(
     # Generate the (ht, dt) quantized Sobol tile for points
     # [skip + j*dt, skip + (j+1)*dt) — `skip` drops the leading points,
     # point 0 (all zeros) being degenerate, exactly like the table path.
-    idx = (j * block_d + jax.lax.iota(jnp.uint32, block_d)) + jnp.uint32(skip)
-    gray = idx ^ (idx >> jnp.uint32(1))
-    acc = jnp.zeros((dir_ref.shape[0], block_d), jnp.uint32)
-    dirs = dir_ref[...]
-    for bit in range(n_bits):
-        mask = ((gray >> jnp.uint32(bit)) & jnp.uint32(1)).astype(jnp.uint32)
-        acc = acc ^ (mask[None, :] * dirs[:, bit : bit + 1])
-    s = (acc >> jnp.uint32(shift)).astype(jnp.int32)
+    s = _sobol_tile(dir_ref[...], j * block_d + skip, block_d, shift, n_bits)
 
     ge = x_ref[...][:, :, None] >= s[None, :, :]
     o_ref[...] += 2 * ge.sum(axis=1, dtype=jnp.int32) - ht
@@ -126,7 +152,7 @@ def encode_bundle_dynamic_pallas(
     shift: int = 0,
     skip: int = 1,
     block_b: int = 8,
-    block_h: int = 112,
+    block_h: int = 128,
     block_d: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
@@ -165,7 +191,7 @@ def encode_bundle_dynamic_pallas(
 
 
 def _fit_bundle_kernel(x_ref, s_ref, oh_ref, o_ref, *, ht: int):
-    """x (bt, ht) i32, s (ht, dt) i32, oh (cp, bt) i32 -> acc o (cp, dt).
+    """x (bt, ht) i32, s (ht, dt) i32, oh (bt, cp) i32 -> acc o (cp, dt).
 
     The (bt, dt) hypervector slab lives only in VREG/VMEM; it is
     contracted against the label indicator in int32 (exact) before the
@@ -179,9 +205,7 @@ def _fit_bundle_kernel(x_ref, s_ref, oh_ref, o_ref, *, ht: int):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     ge = x_ref[...][:, :, None] >= s_ref[...][None, :, :]  # (bt, ht, dt)
-    hv = 2 * ge.sum(axis=1, dtype=jnp.int32) - ht  # (bt, dt)
-    oh = oh_ref[...]  # (cp, bt)
-    o_ref[...] += (oh[:, :, None] * hv[None, :, :]).sum(axis=1, dtype=jnp.int32)
+    _bundle_into(o_ref, oh_ref[...], 2 * ge.sum(axis=1, dtype=jnp.int32) - ht)
 
 
 def fit_bundle_pallas(
@@ -190,20 +214,20 @@ def fit_bundle_pallas(
     onehot: jax.Array,
     *,
     block_b: int = 8,
-    block_h: int = 112,
+    block_h: int = 128,
     block_d: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
     """Fused encode+bundle+class-sum over a threshold table.
 
-    x_q: (B, H) int32, sobol_q: (H, D) int32, onehot: (C, B) int32.
+    x_q: (B, H) int32, sobol_q: (H, D) int32, onehot: (B, C) int32.
     Requires B/H/D divisible by their blocks (ops.py pads + corrects);
     C rides whole in one block.  Returns (C, D) int32 class sums.
     """
     b, h = x_q.shape
     h2, d = sobol_q.shape
-    c = onehot.shape[0]
-    assert h == h2 and onehot.shape[1] == b
+    c = onehot.shape[1]
+    assert h == h2 and onehot.shape[0] == b
     assert b % block_b == 0 and h % block_h == 0 and d % block_d == 0
 
     grid = (d // block_d, b // block_b, h // block_h)
@@ -213,7 +237,7 @@ def fit_bundle_pallas(
         in_specs=[
             pl.BlockSpec((block_b, block_h), lambda j, i, k: (i, k)),
             pl.BlockSpec((block_h, block_d), lambda j, i, k: (k, j)),
-            pl.BlockSpec((c, block_b), lambda j, i, k: (0, i)),
+            pl.BlockSpec((block_b, c), lambda j, i, k: (i, 0)),
         ],
         out_specs=pl.BlockSpec((c, block_d), lambda j, i, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((c, d), jnp.int32),
@@ -227,7 +251,7 @@ def _fit_bundle_dyn_kernel(
 ):
     """Table-free fit_bundle: thresholds generated in VMEM per D-tile.
 
-    `skip_ref` is a (1, 1) int32 *runtime* scalar (unlike the static
+    `skip_ref` is a (1, 1) int32 *runtime* scalar in SMEM (unlike the static
     `skip` of the encode kernel): under D-axis sharding each shard
     passes ``sobol_skip + axis_index * d_local``, which is traced — so
     the first generated point index must be data, not a compile-time
@@ -241,21 +265,9 @@ def _fit_bundle_dyn_kernel(
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    idx = (j * block_d + jax.lax.iota(jnp.uint32, block_d)) + skip_ref[
-        0, 0
-    ].astype(jnp.uint32)
-    gray = idx ^ (idx >> jnp.uint32(1))
-    acc = jnp.zeros((dir_ref.shape[0], block_d), jnp.uint32)
-    dirs = dir_ref[...]
-    for bit in range(n_bits):
-        mask = ((gray >> jnp.uint32(bit)) & jnp.uint32(1)).astype(jnp.uint32)
-        acc = acc ^ (mask[None, :] * dirs[:, bit : bit + 1])
-    s = (acc >> jnp.uint32(shift)).astype(jnp.int32)
-
+    s = _sobol_tile(dir_ref[...], j * block_d + skip_ref[0, 0], block_d, shift, n_bits)
     ge = x_ref[...][:, :, None] >= s[None, :, :]
-    hv = 2 * ge.sum(axis=1, dtype=jnp.int32) - ht
-    oh = oh_ref[...]
-    o_ref[...] += (oh[:, :, None] * hv[None, :, :]).sum(axis=1, dtype=jnp.int32)
+    _bundle_into(o_ref, oh_ref[...], 2 * ge.sum(axis=1, dtype=jnp.int32) - ht)
 
 
 def fit_bundle_dynamic_pallas(
@@ -267,21 +279,21 @@ def fit_bundle_dynamic_pallas(
     *,
     shift: int = 0,
     block_b: int = 8,
-    block_h: int = 112,
+    block_h: int = 128,
     block_d: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
     """Fused encode+bundle+class-sum with in-kernel Sobol generation.
 
-    x_q: (B, H) int32; direction: (H, n_bits) uint; onehot: (C, B) int32;
+    x_q: (B, H) int32; direction: (H, n_bits) uint; onehot: (B, C) int32;
     skip: (1, 1) int32 first-point index (may be traced — see the kernel
     docstring).  Returns (C, d) int32 class sums; neither the (H, D)
     threshold table nor the (B, D) hypervector batch ever touches HBM.
     """
     b, h = x_q.shape
     h2, n_bits = direction.shape
-    c = onehot.shape[0]
-    assert h == h2 and onehot.shape[1] == b
+    c = onehot.shape[1]
+    assert h == h2 and onehot.shape[0] == b
     assert b % block_b == 0 and h % block_h == 0 and d % block_d == 0
 
     grid = (d // block_d, b // block_b, h // block_h)
@@ -297,8 +309,8 @@ def fit_bundle_dynamic_pallas(
         in_specs=[
             pl.BlockSpec((block_b, block_h), lambda j, i, k: (i, k)),
             pl.BlockSpec((block_h, n_bits), lambda j, i, k: (k, 0)),
-            pl.BlockSpec((c, block_b), lambda j, i, k: (0, i)),
-            pl.BlockSpec((1, 1), lambda j, i, k: (0, 0)),
+            pl.BlockSpec((block_b, c), lambda j, i, k: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((c, block_d), lambda j, i, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((c, d), jnp.int32),

@@ -5,9 +5,11 @@ k-best without ever materializing it: the grid is (B/bt, C/ct) with the
 row-tile axis innermost, and *both* outputs — (bt, k) distances and
 (bt, k) indices — map every j to the same block (``lambda i, j:
 (i, 0)``), the Pallas revisiting pattern.  Each j-step XOR+popcounts
-one (ct, W) row tile against the resident (bt, W) query block, appends
-the ct candidates to the k carried in the output refs, and re-selects
-the k best.  At C=1M / D=8192 the stream is ~1 GB of packed rows read
+one (ct, W) row tile against the resident (bt, W) query block (8 query
+rows at a time into a (bt, ct) VMEM scratch, so the working set is
+bounded by ct * W, never by C), appends the ct candidates to the k
+carried in the output refs, and re-selects the k best.  At C=1M /
+D=8192 the stream is ~1 GB of packed rows read
 once per query block — pure memory bandwidth, which is exactly what
 `benchmarks/search_bench.py` measures against the roofline.
 
@@ -32,14 +34,15 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.hamming_packed import round_up
+from repro.kernels.hamming_packed import popcount_distances, round_up
 
 _I32_MAX = np.iinfo(np.int32).max
 
 
-def _topk_kernel(q_ref, c_ref, idx_ref, dist_ref, *, k: int, block_c: int,
-                 c_actual: int):
+def _topk_kernel(q_ref, c_ref, idx_ref, dist_ref, dist_scr, *, k: int,
+                 block_c: int, c_actual: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -47,11 +50,11 @@ def _topk_kernel(q_ref, c_ref, idx_ref, dist_ref, *, k: int, block_c: int,
         idx_ref[...] = jnp.full(idx_ref.shape, _I32_MAX, jnp.int32)
         dist_ref[...] = jnp.full(dist_ref.shape, _I32_MAX, jnp.int32)
 
-    q = q_ref[...]  # (bt, W) uint32, resident across all j
-    c = c_ref[...]  # (ct, W) uint32, this tile of the row stream
-    bt = q.shape[0]
-    pc = jax.lax.population_count(q[:, None, :] ^ c[None, :, :])
-    dist_t = pc.astype(jnp.int32).sum(-1)  # (bt, ct) Hamming distances
+    # (bt, ct) Hamming distances of the resident query block against
+    # this tile of the row stream, 8 query rows at a time.
+    popcount_distances(q_ref, c_ref, dist_scr)
+    dist_t = dist_scr[...]
+    bt = dist_t.shape[0]
     gidx = j * block_c + jax.lax.broadcasted_iota(jnp.int32, (bt, block_c), 1)
     valid = gidx < c_actual  # grid-padded rows never win
     dist_t = jnp.where(valid, dist_t, _I32_MAX)
@@ -93,34 +96,40 @@ def hamming_topk_pallas(
     B and C are arbitrary: operands are zero-padded up to the block
     grid; padded query rows are sliced off the result and padded store
     rows are masked to the sentinel in-kernel (their global index is
-    >= C), so they never appear in a result.
+    >= C), so they never appear in a result.  The row tile shrinks with
+    W (at most 2^16 words, so the (8, ct, W) XOR cube stays ~2 MiB at
+    any store size) and to the store itself when C is small (the C~10
+    predict path); the query tile shrinks to a small B.
     """
     b, w = q_words.shape
     c, w2 = c_words.shape
     assert w == w2
     if not 1 <= k <= c:
         raise ValueError(f"k must be in [1, {c}], got {k}")
-    bp, cp = round_up(b, block_b), round_up(c, block_c)
+    bt = min(block_b, round_up(b, 8))
+    ct = min(block_c, max(8, (1 << 16) // w // 8 * 8), round_up(c, 8))
+    bp, cp = round_up(b, bt), round_up(c, ct)
     if bp != b:
         q_words = jnp.pad(q_words, ((0, bp - b), (0, 0)))
     if cp != c:
         c_words = jnp.pad(c_words, ((0, cp - c), (0, 0)))
 
     idx, dist = pl.pallas_call(
-        functools.partial(_topk_kernel, k=k, block_c=block_c, c_actual=c),
-        grid=(bp // block_b, cp // block_c),
+        functools.partial(_topk_kernel, k=k, block_c=ct, c_actual=c),
+        grid=(bp // bt, cp // ct),
         in_specs=[
-            pl.BlockSpec((block_b, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_c, w), lambda i, j: (j, 0)),
+            pl.BlockSpec((bt, w), lambda i, j: (i, 0)),
+            pl.BlockSpec((ct, w), lambda i, j: (j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_b, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((bt, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((bt, k), lambda i, j: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bp, k), jnp.int32),
             jax.ShapeDtypeStruct((bp, k), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((bt, ct), jnp.int32)],
         interpret=interpret,
     )(q_words, c_words)
     return idx[:b], dist[:b]

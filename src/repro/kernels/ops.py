@@ -52,7 +52,7 @@ def encode_bundle(
     sobol_q: jax.Array,
     *,
     block_b: int = 8,
-    block_h: int = 112,
+    block_h: int = 128,
     block_d: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -85,7 +85,7 @@ def encode_bundle_dynamic(
     levels: int | None = None,
     skip: int = 1,
     block_b: int = 8,
-    block_h: int = 112,
+    block_h: int = 128,
     block_d: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -127,16 +127,17 @@ def encode_bundle_dynamic(
 
 
 def _padded_class_onehot(labels: jax.Array, c_pad: int, b_pad: int) -> jax.Array:
-    """(B,) labels -> (c_pad, b_pad) int32 indicator via ref.class_onehot.
+    """(B,) labels -> batch-major (b_pad, c_pad) int32 indicator, the
+    transpose of ref.class_onehot (the fit kernels block it (bt, cp)).
 
-    Padded batch columns carry label -1 and padded class rows match no
+    Padded batch rows carry label -1 and padded class columns match no
     real label, so both drop out with zero weight — the same
     out-of-range drop contract as the unpadded indicator.
     """
     lp = jnp.pad(
         labels.astype(jnp.int32), (0, b_pad - labels.shape[0]), constant_values=-1
     )
-    return ref.class_onehot(lp, c_pad)
+    return ref.class_onehot(lp, c_pad).T
 
 
 def fit_bundle(
@@ -146,7 +147,7 @@ def fit_bundle(
     n_classes: int,
     *,
     block_b: int = 8,
-    block_h: int = 112,
+    block_h: int = 128,
     block_d: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -175,7 +176,7 @@ def fit_bundle(
         xp, sp, oh, block_b=block_b, block_h=block_h, block_d=block_d,
         interpret=interpret,
     )
-    counts = oh[:n_classes].sum(axis=1, dtype=jnp.int32)
+    counts = oh[:, :n_classes].sum(axis=0, dtype=jnp.int32)
     return out[:n_classes, :d] + (hp - h) * counts[:, None]
 
 
@@ -189,7 +190,7 @@ def fit_bundle_dynamic(
     levels: int | None = None,
     skip: int | jax.Array = 1,
     block_b: int = 8,
-    block_h: int = 112,
+    block_h: int = 128,
     block_d: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -216,7 +217,7 @@ def fit_bundle_dynamic(
         xp, dirp, oh, skip, dp, shift=shift, block_b=block_b, block_h=block_h,
         block_d=block_d, interpret=interpret,
     )
-    counts = oh[:n_classes].sum(axis=1, dtype=jnp.int32)
+    counts = oh[:, :n_classes].sum(axis=0, dtype=jnp.int32)
     return out[:n_classes, :d] + (hp - h) * counts[:, None]
 
 
@@ -290,7 +291,7 @@ def hamming_packed(
     d: int,
     *,
     block_b: int = 128,
-    block_c: int = 8,
+    block_c: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Packed ±1 similarity. (B,W),(C,W) uint32 -> (B,C) int32."""
@@ -319,13 +320,11 @@ def hamming_topk(
     """
     if interpret is None:
         interpret = _interpret_default()
-    c = c_words.shape[0]
-    # Small stores (the C~10 predict path) shrink the row tile so one
-    # grid step covers the store without 25x padded XOR work.
-    bc = min(block_c, _round_up(max(c, 8), 8))
-    # padding to the block grid happens inside hamming_topk_pallas
+    # tile choice and padding to the block grid happen inside
+    # hamming_topk_pallas
     return hamming_topk_pallas(
-        q_words, c_words, d, k, block_b=block_b, block_c=bc, interpret=interpret
+        q_words, c_words, d, k, block_b=block_b, block_c=block_c,
+        interpret=interpret,
     )
 
 

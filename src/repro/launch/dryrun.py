@@ -69,8 +69,6 @@ def _lower(cfg, shape, inputs):
 
 def _cell_stats(compiled) -> dict:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns one dict per device
-        ca = ca[0] if ca else {}
     coll = roofline.collective_bytes(compiled.as_text())
     counts = coll.pop("_counts")
     return {

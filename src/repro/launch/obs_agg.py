@@ -46,6 +46,7 @@ from repro.obs.prometheus import parse_exposition
 from repro.serving import ModelRegistry
 from repro.serving.metrics import ServingMetrics
 from repro.transport import HdcClient, HdcHttpServer, TransportError
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _wait_for_cycles(agg: FleetAggregator, n: int, timeout_s: float = 30.0):
@@ -271,6 +272,7 @@ def main(argv=None) -> int:
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--backend", default="auto")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         return run_smoke(args)

@@ -29,6 +29,7 @@ import numpy as np
 from repro.core import HDCConfig, HDCModel
 from repro.data import load_dataset
 from repro.serving import ModelRegistry, ServingEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _print_stats(name: str, snap: dict, n_served: int, serve_wall_s: float) -> None:
@@ -157,6 +158,7 @@ def main(argv=None) -> int:
     ap.add_argument("--impl", default="auto",
                     help="packed similarity: auto | pallas | jnp")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         return run_smoke(args)

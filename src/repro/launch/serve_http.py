@@ -50,6 +50,7 @@ from repro.core import HDCConfig, HDCModel
 from repro.data import load_dataset
 from repro.serving import ModelRegistry
 from repro.transport import HdcClient, HdcHttpServer, ReloadWatcher, TransportError
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _stream_over_http(
@@ -330,6 +331,7 @@ def main(argv=None) -> int:
                     help="allow POST /v1/debug/profile (jax.profiler "
                          "capture); off by default")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         return run_smoke(args)

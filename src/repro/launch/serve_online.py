@@ -42,6 +42,7 @@ from repro.data import load_dataset
 from repro.online import OnlineLearner
 from repro.serving import ModelRegistry
 from repro.transport import HdcClient, HdcHttpServer, ReloadWatcher
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _predict_all(client: HdcClient, name: str, images, chunk: int = 64) -> np.ndarray:
@@ -238,6 +239,7 @@ def main(argv=None) -> int:
                     help="checkpoint retention for learner publishes")
     ap.add_argument("--max-queue-depth", type=int, default=1024)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         return run_smoke(args)

@@ -24,6 +24,7 @@ from repro.core import HDCConfig, HDCModel, baseline_iterative_search
 from repro.data import load_dataset
 from repro.distributed.sharding import set_current_mesh
 from repro.launch.mesh import mesh_for
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -59,6 +60,7 @@ def main(argv=None) -> int:
     ap.add_argument("--compare-baseline", action="store_true")
     ap.add_argument("--baseline-iters", type=int, default=5)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     mesh = mesh_for()
     set_current_mesh(mesh)

@@ -160,7 +160,6 @@ def _moe_ffn_local(
     """
     import math as _math
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, t, d = x.shape
@@ -200,7 +199,7 @@ def _moe_ffn_local(
         return y, aux
 
     bspec = (batch_axes if len(batch_axes) > 1 else batch_axes[0]) if batch_axes else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -211,7 +210,7 @@ def _moe_ffn_local(
             P("model", None, None),
         ),
         out_specs=(P(bspec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 

@@ -104,9 +104,16 @@ class DeviceExecution:
     def pack(self, model: HDCModel) -> jax.Array:
         return model.pack()
 
+    def _inputs(self, images) -> jax.Array:
+        # straight onto the pinned device: a detour through the default
+        # device would cost every replica but the first an extra copy
+        if self.device is None:
+            return jnp.asarray(images)
+        return jax.device_put(images, self.device)
+
     def predict(self, model: HDCModel, class_words: jax.Array, images) -> jax.Array:
         return hdc_model.predict_packed(
-            model, jnp.asarray(images), class_words, impl=self.impl
+            model, self._inputs(images), class_words, impl=self.impl
         )
 
     def search(
@@ -115,7 +122,7 @@ class DeviceExecution:
         """Scored top-k over the packed store (DESIGN.md §14): the k
         nearest rows per query, ascending (distance, index)."""
         return hdc_model.search_packed(
-            model, jnp.asarray(images), class_words, k=k, impl=self.impl
+            model, self._inputs(images), class_words, k=k, impl=self.impl
         )
 
     def describe(self) -> dict:
@@ -144,7 +151,6 @@ def _sharded_pack_fn(cfg, mesh: Mesh, rules: ShardingRules):
     slice after globally-exact centering -> (C, n_shards * W_local)
     uint32, D-partitioned.  Per-shard word layout matches what the
     sharded predict packs queries into, so XOR pads cancel."""
-    from jax.experimental.shard_map import shard_map
 
     axis = model_axis_for(mesh, cfg.d, rules=rules)
     enc = registry.get_encoder(cfg.encoder)
@@ -161,9 +167,9 @@ def _sharded_pack_fn(cfg, mesh: Mesh, rules: ShardingRules):
     def step(m: HDCModel) -> jax.Array:
         return unary.pack_hypervector(_centered_local(cfg, m.class_hvs, axis))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         step, mesh=mesh, in_specs=(mspecs,), out_specs=P(None, axis),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -177,7 +183,6 @@ def _sharded_predict_fn(cfg, mesh: Mesh, impl: str, rules: ShardingRules):
     their pre-sliced codebook) -> center/pack -> partial XOR+popcount
     score -> **one psum** -> argmax, replicated.
     """
-    from jax.experimental.shard_map import shard_map
 
     axis = model_axis_for(mesh, cfg.d, rules=rules)
     n_shards = mesh.shape[axis]
@@ -210,11 +215,11 @@ def _sharded_predict_fn(cfg, mesh: Mesh, impl: str, rules: ShardingRules):
         sim = jax.lax.psum(sim_local, axis)
         return metrics.classify(sim.astype(jnp.float32))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         step, mesh=mesh,
         in_specs=(mspecs, P(), P(None, axis)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -236,7 +241,6 @@ def _sharded_search_fn(cfg, mesh: Mesh, impl: str, k: int, rules: ShardingRules)
     bit-identical to the single-device oracle — including ties and
     ``d_local % 32 != 0``.
     """
-    from jax.experimental.shard_map import shard_map
 
     axis = model_axis_for(mesh, cfg.d, rules=rules)
     n_shards = mesh.shape[axis]
@@ -271,11 +275,11 @@ def _sharded_search_fn(cfg, mesh: Mesh, impl: str, k: int, rules: ShardingRules)
         dist = jax.lax.psum(dist_local, axis)
         return kref.topk_pinned(dist, k)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         step, mesh=mesh,
         in_specs=(mspecs, P(), P(None, axis)),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 

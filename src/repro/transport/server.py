@@ -214,10 +214,9 @@ class AsyncHttpServer:
 
     async def _shutdown(self, *, drain: bool, timeout_s: float) -> None:
         self._closing = True
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+        server, self._server = self._server, None
+        if server is not None:
+            server.close()  # stop accepting
         # idle keep-alive connections (parked in readline waiting for a
         # next request) are cancelled immediately; busy ones — a request
         # is being served — get the drain window
@@ -230,6 +229,10 @@ class AsyncHttpServer:
             for t in pending:  # stragglers past the drain window
                 t.cancel()
             await asyncio.gather(*pending, return_exceptions=True)
+        # last: since Python 3.12.1 wait_closed() also waits for every
+        # open connection, so it must follow the cancellations above
+        if server is not None:
+            await server.wait_closed()
 
     @property
     def address(self) -> tuple[str, int]:

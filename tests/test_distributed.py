@@ -159,7 +159,6 @@ def test_compressed_grad_sync_multidevice_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.distributed import compress
         from repro.launch.mesh import _make_mesh
         mesh = _make_mesh((2, 4), ("pod", "data"))
@@ -167,7 +166,7 @@ def test_compressed_grad_sync_multidevice_subprocess():
         errors = {"w": jnp.zeros((8, 1))}
         def sync(g, e):
             return compress.compressed_grad_sync(g, e)
-        out, err = jax.jit(shard_map(
+        out, err = jax.jit(jax.shard_map(
             sync, mesh=mesh,
             in_specs=(P(("pod", "data")), P(("pod", "data"))),
             out_specs=(P(("pod", "data")), P(("pod", "data"))),

@@ -1,0 +1,95 @@
+"""Main-path Pallas kernels compile for a described TPU v5e chip.
+
+Interpret mode (every other kernel test) cannot see the TPU's block
+rules — the last two block dims must be multiples of (8, 128) or the
+array's own — nor the scoped VMEM limit.  The TPU compiler is
+installed even where no chip is attached, so each kernel is compiled
+here at the paper's widths (H=784, C=10, D=8192, 16 levels) and at the
+store and shard sizes the system serves.  Nothing runs: these tests
+say a kernel compiles natively, not that it is right or fast.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+H, C, D = 784, 10, 8192
+W = D // 32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without one, so keep it out of the cache
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+# name -> (fn, operand shapes/dtypes); every call pins interpret=False,
+# because ops' own default sees the CPU here
+KERNELS = {
+    "encode_bundle_B64": (
+        lambda x, s: ops.encode_bundle(x, s, interpret=False),
+        [((64, H), jnp.int32), ((H, D), jnp.int8)],
+    ),
+    "encode_bundle_dynamic_B64": (
+        lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, interpret=False),
+        [((64, H), jnp.int32), ((H, 32), jnp.uint8)],
+    ),
+    "fit_bundle_B256": (
+        lambda x, s, y: ops.fit_bundle(x, s, y, C, interpret=False),
+        [((256, H), jnp.int32), ((H, D), jnp.int8), ((256,), jnp.int32)],
+    ),
+    "fit_bundle_dynamic_B256": (
+        lambda x, dr, y: ops.fit_bundle_dynamic(x, dr, y, C, D, interpret=False),
+        [((256, H), jnp.int32), ((H, 32), jnp.uint8), ((256,), jnp.int32)],
+    ),
+    "hamming_packed_C10": (
+        lambda q, c: ops.hamming_packed(q, c, D, interpret=False),
+        [((64, W), jnp.uint32), ((C, W), jnp.uint32)],
+    ),
+    # one shard of a four-chip D-sharded engine: d_local=2048, W=64
+    "hamming_packed_W64": (
+        lambda q, c: ops.hamming_packed(q, c, D // 4, interpret=False),
+        [((64, W // 4), jnp.uint32), ((C, W // 4), jnp.uint32)],
+    ),
+    "hamming_topk_C10_k1": (
+        lambda q, c: ops.hamming_topk(q, c, D, 1, interpret=False),
+        [((64, W), jnp.uint32), ((C, W), jnp.uint32)],
+    ),
+    # a 1 GiB store: the working set must not grow with C
+    "hamming_topk_C1M_k10": (
+        lambda q, c: ops.hamming_topk(q, c, D, 10, interpret=False),
+        [((64, W), jnp.uint32), ((1 << 20, W), jnp.uint32)],
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, operands = KERNELS[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in operands]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), "no native kernel in the program"
