@@ -40,6 +40,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import encoding, metrics, registry, unary
 from repro.core.model import HDCConfig
+from repro.obs.profiler import span
 
 
 # ---------------------------------------------------------------------------
@@ -240,9 +241,14 @@ class HDCModel:
         accumulator is updated in place instead of re-allocated per
         batch (this model's own buffers are untouched: the stream
         starts from a fresh `reset` copy)."""
-        model = self.reset()
+        with span("hdc.fit.reset"):
+            model = self.reset()
         for images, labels in batches:
-            model = model.partial_fit(images, labels, donate=True)
+            # the step's host work: label check, stateless view, donated
+            # dispatch; it also holds the wait while the device's queue
+            # of programs is full
+            with span("hdc.fit.step"):
+                model = model.partial_fit(images, labels, donate=True)
         return model
 
     def reset(self) -> "HDCModel":

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.core import unary
 from repro.core.hdc_model import _packed_topk
+from repro.obs.profiler import span
 
 
 def _default_impl() -> str:
@@ -95,7 +96,8 @@ class ItemMemory:
 
     def _device_rows(self) -> jax.Array:
         if self._dev is None:
-            self._dev = jnp.asarray(self._rows)
+            with span("hdc.store.upload"):  # the whole store, after any mutation
+                self._dev = jnp.asarray(self._rows)
         return self._dev
 
     def search(self, queries, k: int) -> tuple[np.ndarray, np.ndarray]:

@@ -74,4 +74,6 @@ def bundle_binarize_pallas(
         out_shape=jax.ShapeDtypeStruct((c, d), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_d), jnp.float32)],
         interpret=interpret,
+        name="bundle_binarize",
+        metadata={"hdc_kernel": "bundle_binarize"},
     )(onehot_labels.astype(jnp.float32), hvs.astype(jnp.float32))

@@ -115,6 +115,8 @@ def encode_bundle_pallas(
         out_specs=pl.BlockSpec((block_b, block_d), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.int32),
         interpret=interpret,
+        name="encode_bundle",
+        metadata={"hdc_kernel": "encode_bundle"},
     )(x_q.astype(jnp.int32), sobol_q.astype(jnp.int32))
 
 
@@ -187,6 +189,8 @@ def encode_bundle_dynamic_pallas(
         out_specs=pl.BlockSpec((block_b, block_d), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.int32),
         interpret=interpret,
+        name="encode_bundle_dynamic",
+        metadata={"hdc_kernel": "encode_bundle_dynamic"},
     )(x_q.astype(jnp.int32), direction.astype(jnp.uint32))
 
 
@@ -242,6 +246,8 @@ def fit_bundle_pallas(
         out_specs=pl.BlockSpec((c, block_d), lambda j, i, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((c, d), jnp.int32),
         interpret=interpret,
+        name="fit_bundle",
+        metadata={"hdc_kernel": "fit_bundle"},
     )(x_q.astype(jnp.int32), sobol_q.astype(jnp.int32), onehot.astype(jnp.int32))
 
 
@@ -315,6 +321,8 @@ def fit_bundle_dynamic_pallas(
         out_specs=pl.BlockSpec((c, block_d), lambda j, i, k: (0, j)),
         out_shape=jax.ShapeDtypeStruct((c, d), jnp.int32),
         interpret=interpret,
+        name="fit_bundle_dynamic",
+        metadata={"hdc_kernel": "fit_bundle_dynamic"},
     )(
         x_q.astype(jnp.int32),
         direction.astype(jnp.uint32),

@@ -72,4 +72,6 @@ def encode_unary_mxu_pallas(
         out_specs=pl.BlockSpec((block_b, block_d), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.int32),
         interpret=interpret,
+        name="encode_unary_mxu",
+        metadata={"hdc_kernel": "encode_unary_mxu"},
     )(u.astype(jnp.bfloat16), onehot_s.astype(jnp.bfloat16))

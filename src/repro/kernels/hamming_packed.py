@@ -85,5 +85,7 @@ def hamming_packed_pallas(
         out_specs=pl.BlockSpec((bt, ct), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, cp), jnp.int32),
         interpret=interpret,
+        name="hamming_packed",
+        metadata={"hdc_kernel": "hamming_packed"},
     )(q_words, c_words)
     return out[:b, :c]

@@ -131,5 +131,7 @@ def hamming_topk_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((bt, ct), jnp.int32)],
         interpret=interpret,
+        name="hamming_topk",
+        metadata={"hdc_kernel": "hamming_topk"},
     )(q_words, c_words)
     return idx[:b], dist[:b]

@@ -26,10 +26,13 @@ Core primitives, all stdlib + thread-safe, shared by `repro.serving`,
     ``Accept: text/plain``; :func:`parse_exposition` is its strict
     inverse (duplicate HELP/TYPE and escaping are machine-checked).
 
-Plus the device-step profiling hooks: :class:`timed_block` (a
-``block_until_ready`` timing context around the jitted predict) and
+Plus the profiling hooks, which `repro.core` uses too: :class:`span`
+(a named stretch of host work on the ``jax.profiler`` clock that also
+keeps its wall time for the stage histograms; names take the form ``hdc.<layer>.<what>``, e.g.
+``hdc.engine.step``), :func:`install_gc_span` / :func:`remove_gc_span`
+(``python.gc`` spans around garbage collections) and
 :func:`profile_capture` (an opt-in ``jax.profiler`` trace window behind
-``POST /v1/debug/profile``).
+``POST /v1/debug/profile``).  JAX is imported only when a span opens.
 
 The fleet aggregation plane (`FleetAggregator`, `AggregatorServer`,
 scrape targets) lives in ``repro.obs.aggregator`` and is **not**
@@ -39,7 +42,12 @@ these primitives), so an eager import would create a cycle.  Import
 """
 
 from repro.obs.histogram import LatencyHistogram  # noqa: F401
-from repro.obs.profiler import profile_capture, timed_block  # noqa: F401
+from repro.obs.profiler import (  # noqa: F401
+    install_gc_span,
+    profile_capture,
+    remove_gc_span,
+    span,
+)
 from repro.obs.prometheus import (  # noqa: F401
     parse_exposition,
     render_prometheus,

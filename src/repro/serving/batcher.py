@@ -48,7 +48,7 @@ import time
 
 import numpy as np
 
-from repro.obs.profiler import timed_block
+from repro.obs.profiler import span
 from repro.obs.trace import OWNER_BATCHER, OWNER_TRANSPORT, RequestTrace, TraceBuffer
 from repro.serving.engine import ServingEngine
 from repro.serving.metrics import ServingMetrics
@@ -345,34 +345,46 @@ class MicroBatcher:
                     fut.trace.t_dequeue = t_dequeue
         return engine, op, taken
 
+    def _assemble(self) -> tuple[
+        ServingEngine, tuple[str, int], list[tuple[np.ndarray, ServingFuture]],
+        np.ndarray | None,
+    ]:
+        """Take the next batch (under the lock) and pad it to the
+        engine's static slot count; no batch when the queue is empty."""
+        with span("hdc.batcher.assemble"):
+            with self._cv:
+                engine, op, taken = self._take_batch()
+            if not taken:
+                return engine, op, taken, None
+            slots = engine.batch_size
+            batch = np.zeros((slots, engine.model.cfg.n_features), np.float32)
+            for i, (image, _) in enumerate(taken):
+                batch[i] = image  # pad rows stay zero
+        self.metrics.observe_batch(len(taken), slots)
+        return engine, op, taken, batch
+
     def _run_batch(
         self,
         engine: ServingEngine,
         op: tuple[str, int],
         taken: list[tuple[np.ndarray, ServingFuture]],
+        batch: np.ndarray,
     ) -> None:
-        slots = engine.batch_size
-        h = engine.model.cfg.n_features
-        batch = np.zeros((slots, h), np.float32)  # pad rows stay zero
-        for i, (image, _) in enumerate(taken):
-            batch[i] = image
-        self.metrics.observe_batch(len(taken), slots)
         t_device_start = time.perf_counter()
         for _, fut in taken:
             if fut.trace is not None:
                 fut.trace.t_device_start = t_device_start
                 fut.trace.step = engine.step
         try:
-            with timed_block("device") as tb:
+            # the step ends with its results on the host, one copy per
+            # array, sliced there
+            with span("hdc.engine.step") as step:
                 if op[0] == "search":
                     indices, dists = engine.search(batch, op[1])
-                    tb.sync((indices, dists))
-                    results = [
-                        (np.asarray(indices[i]), np.asarray(dists[i]))
-                        for i in range(len(taken))
-                    ]
+                    indices, dists = np.asarray(indices), np.asarray(dists)
+                    results = [(indices[i], dists[i]) for i in range(len(taken))]
                 else:
-                    labels = tb.sync(engine.predict(batch))
+                    labels = np.asarray(engine.predict(batch))
                     results = [int(labels[i]) for i in range(len(taken))]
         except Exception as e:  # deliver the failure, keep serving
             for _, fut in taken:
@@ -381,7 +393,7 @@ class MicroBatcher:
                 self._finish_request(fut, error=True)
                 fut._resolve(None, e)
             return
-        t_device_end = t_device_start + tb.elapsed_s
+        t_device_end = t_device_start + step.elapsed_s
         # metrics/traces are recorded BEFORE the resolve wakes the waiter,
         # so a scrape issued after a response arrives never reads a
         # counter that has not seen that request yet
@@ -421,10 +433,9 @@ class MicroBatcher:
 
     def step(self) -> int:
         """Serve one micro-batch synchronously; returns requests served."""
-        with self._cv:
-            engine, op, taken = self._take_batch()
+        engine, op, taken, batch = self._assemble()
         if taken:
-            self._run_batch(engine, op, taken)
+            self._run_batch(engine, op, taken, batch)
         return len(taken)
 
     def flush(self) -> int:
@@ -436,29 +447,40 @@ class MicroBatcher:
                 return total
             total += n
 
+    def _wait_for_work(self) -> bool:
+        """Block until requests are queued and the coalescing window
+        has passed (or the batch is full); False once stopped and empty."""
+        with self._cv:
+            while self._running and not self._queue:
+                self._cv.wait(0.05)
+            if not self._running and not self._queue:
+                return False
+            # coalescing window: give a trickle of traffic a chance
+            # to fill more slots before paying a device launch (loop
+            # on a deadline — each submit notifies the condition, so
+            # a single wait would collapse on the first arrival)
+            deadline = time.perf_counter() + self.max_delay_s
+            while (
+                self._running
+                and self._n_queued < self.engine.batch_size
+            ):
+                remaining = deadline - time.perf_counter()
+                if remaining <= 0:
+                    break
+                self._cv.wait(remaining)
+            return True
+
     def _drain_loop(self) -> None:
         while True:
-            with self._cv:
-                while self._running and not self._queue:
-                    self._cv.wait(0.05)
-                if not self._running and not self._queue:
+            with span("hdc.batcher.wait"):
+                if not self._wait_for_work():
                     return
-                # coalescing window: give a trickle of traffic a chance
-                # to fill more slots before paying a device launch (loop
-                # on a deadline — each submit notifies the condition, so
-                # a single wait would collapse on the first arrival)
-                deadline = time.perf_counter() + self.max_delay_s
-                while (
-                    self._running
-                    and self._n_queued < self.engine.batch_size
-                ):
-                    remaining = deadline - time.perf_counter()
-                    if remaining <= 0:
-                        break
-                    self._cv.wait(remaining)
-                engine, op, taken = self._take_batch()
+            # the lock is dropped between the wait and the take: a stop
+            # in between leaves the queue empty (next wait returns) or
+            # drained by this loop, never stranded
+            engine, op, taken, batch = self._assemble()
             if taken:
-                self._run_batch(engine, op, taken)
+                self._run_batch(engine, op, taken, batch)
 
     def start(self) -> "MicroBatcher":
         """Start the background drain thread (idempotent; reopens a

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.hdc_model import HDCModel
+from repro.obs.profiler import span
 from repro.serving.execution import DeviceExecution, resolve_impl
 
 __all__ = ["ServingEngine", "resolve_impl"]
@@ -103,7 +104,8 @@ class ServingEngine:
         exactly once.
         """
         labels = self.execution.predict(self.model, self.class_words, images)
-        return np.asarray(labels)
+        with span("hdc.engine.read"):  # waits for the device, then copies
+            return np.asarray(labels)
 
     def search(self, images, k: int) -> tuple[np.ndarray, np.ndarray]:
         """(B, H) raw images -> ((B, k) int32 row indices, (B, k) int32
@@ -119,7 +121,8 @@ class ServingEngine:
         idx, dist = self.execution.search(
             self.model, self.class_words, images, int(k)
         )
-        return np.asarray(idx), np.asarray(dist)
+        with span("hdc.engine.read"):  # waits for the device, then copies
+            return np.asarray(idx), np.asarray(dist)
 
     def warmup(self) -> "ServingEngine":
         """Compile the static-shape serving path before taking traffic."""

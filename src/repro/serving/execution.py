@@ -53,6 +53,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import encoding, hdc_model, metrics, registry, unary
 from repro.core.hdc_model import HDCModel
 from repro.distributed.sharding import ShardingRules, model_axis_for, model_mesh
+from repro.obs.profiler import span
 
 _IMPLS = ("jnp", "pallas")
 _PLATFORMS = ("cpu", "gpu", "tpu")
@@ -107,9 +108,10 @@ class DeviceExecution:
     def _inputs(self, images) -> jax.Array:
         # straight onto the pinned device: a detour through the default
         # device would cost every replica but the first an extra copy
-        if self.device is None:
-            return jnp.asarray(images)
-        return jax.device_put(images, self.device)
+        with span("hdc.engine.put"):
+            if self.device is None:
+                return jnp.asarray(images)
+            return jax.device_put(images, self.device)
 
     def predict(self, model: HDCModel, class_words: jax.Array, images) -> jax.Array:
         return hdc_model.predict_packed(
@@ -320,7 +322,9 @@ class ShardedExecution:
 
     def predict(self, model: HDCModel, class_words: jax.Array, images) -> jax.Array:
         fn = _sharded_predict_fn(model.cfg, self.mesh, self.impl, self.rules)
-        return fn(model, jnp.asarray(images), class_words)
+        with span("hdc.engine.put"):
+            images = jnp.asarray(images)
+        return fn(model, images, class_words)
 
     def search(
         self, model: HDCModel, class_words: jax.Array, images, k: int
@@ -329,7 +333,9 @@ class ShardedExecution:
         fn = _sharded_search_fn(
             model.cfg, self.mesh, self.impl, int(k), self.rules
         )
-        return fn(model, jnp.asarray(images), class_words)
+        with span("hdc.engine.put"):
+            images = jnp.asarray(images)
+        return fn(model, images, class_words)
 
     def describe(self) -> dict:
         return {

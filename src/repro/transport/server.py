@@ -386,6 +386,31 @@ class HdcHttpServer(AsyncHttpServer):
         # must be an operator decision, never a default
         self.enable_profiling = bool(enable_profiling)
         self.profile_dir = profile_dir
+        self._gc_hooked = False  # python.gc spans installed by start()
+
+    # -- lifecycle ---------------------------------------------------------
+
+    def start(self):
+        """Serve, with the interpreter's garbage collections marked as
+        ``python.gc`` spans on the profiler's clock until `stop`."""
+        _profiler.install_gc_span()
+        self._gc_hooked = True
+        try:
+            return super().start()
+        except BaseException:
+            self._unhook_gc()
+            raise
+
+    def stop(self, *, drain: bool = True, timeout_s: float = 30.0) -> None:
+        try:
+            super().stop(drain=drain, timeout_s=timeout_s)
+        finally:
+            self._unhook_gc()
+
+    def _unhook_gc(self) -> None:
+        if self._gc_hooked:
+            self._gc_hooked = False
+            _profiler.remove_gc_span()
 
     # -- routing -----------------------------------------------------------
 
@@ -604,68 +629,69 @@ class HdcHttpServer(AsyncHttpServer):
             )
         n_features = batcher.engine.model.cfg.n_features
 
-        content_type = request.header("content-type", protocol.CT_JSON)
-        content_type = content_type.split(";")[0].strip().lower()
-        single = False
-        try:
-            if content_type == protocol.CT_F32:
-                images = protocol.decode_images(request.body, n_features)
-            elif content_type == protocol.CT_JSON:
-                images, single = protocol.parse_predict_json(
-                    json.loads(request.body or b"{}")
-                )
-            else:
+        with _profiler.span("hdc.http.decode"):
+            content_type = request.header("content-type", protocol.CT_JSON)
+            content_type = content_type.split(";")[0].strip().lower()
+            single = False
+            try:
+                if content_type == protocol.CT_F32:
+                    images = protocol.decode_images(request.body, n_features)
+                elif content_type == protocol.CT_JSON:
+                    images, single = protocol.parse_predict_json(
+                        json.loads(request.body or b"{}")
+                    )
+                else:
+                    return _Response.error(
+                        HTTPStatus.UNSUPPORTED_MEDIA_TYPE,
+                        f"unsupported content type {content_type!r}; "
+                        f"use {protocol.CT_JSON} or {protocol.CT_F32}",
+                    )
+                if images.shape[1] != n_features:
+                    raise ValueError(
+                        f"model {name!r} takes {n_features} features per image, "
+                        f"got {images.shape[1]}"
+                    )
+            # TypeError too: a JSON body with non-numeric entries (e.g. null)
+            # raises it from np.asarray — that is a malformed payload (400),
+            # not a server bug (500)
+            except (ValueError, TypeError, json.JSONDecodeError) as e:
+                return _Response.error(HTTPStatus.BAD_REQUEST, str(e))
+
+            # -- admission: bounded queue depth -> shed loudly ----------------
+            limit = batcher.max_depth
+            if limit is None:
+                limit = self.max_queue_depth
+            if limit is not None and batcher.queue_depth() + len(images) > limit:
+                batcher.metrics.shed(len(images))
                 return _Response.error(
-                    HTTPStatus.UNSUPPORTED_MEDIA_TYPE,
-                    f"unsupported content type {content_type!r}; "
-                    f"use {protocol.CT_JSON} or {protocol.CT_F32}",
+                    HTTPStatus.TOO_MANY_REQUESTS,
+                    f"model {name!r} overloaded: queue depth "
+                    f"{batcher.queue_depth()} + {len(images)} exceeds {limit}",
+                    retry=True,
                 )
-            if images.shape[1] != n_features:
-                raise ValueError(
-                    f"model {name!r} takes {n_features} features per image, "
-                    f"got {images.shape[1]}"
+
+            loop = asyncio.get_running_loop()
+            # cross-hop trace propagation: a sane x-hdc-request-id header is
+            # adopted (the client minted it, so client and server logs share
+            # one id); anything absent or hostile mints locally as before.
+            # One span set per image (a batch of n fans out to "rid/i").
+            rid = adopt_request_id(
+                request.header(protocol.HDR_REQUEST_ID)
+            ) or new_request_id()
+            request_ids = (
+                [rid] if len(images) == 1
+                else [f"{rid}/{i}" for i in range(len(images))]
+            )
+            try:
+                # all-or-nothing admission: a race with the depth bound or a
+                # concurrent stop() can't strand a half-submitted batch
+                futures = batcher.submit_block(
+                    images, request_ids=request_ids, trace_owner=OWNER_TRANSPORT
                 )
-        # TypeError too: a JSON body with non-numeric entries (e.g. null)
-        # raises it from np.asarray — that is a malformed payload (400),
-        # not a server bug (500)
-        except (ValueError, TypeError, json.JSONDecodeError) as e:
-            return _Response.error(HTTPStatus.BAD_REQUEST, str(e))
-
-        # -- admission: bounded queue depth -> shed loudly ----------------
-        limit = batcher.max_depth
-        if limit is None:
-            limit = self.max_queue_depth
-        if limit is not None and batcher.queue_depth() + len(images) > limit:
-            batcher.metrics.shed(len(images))
-            return _Response.error(
-                HTTPStatus.TOO_MANY_REQUESTS,
-                f"model {name!r} overloaded: queue depth "
-                f"{batcher.queue_depth()} + {len(images)} exceeds {limit}",
-                retry=True,
-            )
-
-        loop = asyncio.get_running_loop()
-        # cross-hop trace propagation: a sane x-hdc-request-id header is
-        # adopted (the client minted it, so client and server logs share
-        # one id); anything absent or hostile mints locally as before.
-        # One span set per image (a batch of n fans out to "rid/i").
-        rid = adopt_request_id(
-            request.header(protocol.HDR_REQUEST_ID)
-        ) or new_request_id()
-        request_ids = (
-            [rid] if len(images) == 1
-            else [f"{rid}/{i}" for i in range(len(images))]
-        )
-        try:
-            # all-or-nothing admission: a race with the depth bound or a
-            # concurrent stop() can't strand a half-submitted batch
-            futures = batcher.submit_block(
-                images, request_ids=request_ids, trace_owner=OWNER_TRANSPORT
-            )
-        except QueueFull as e:  # batcher-level bound won the race; shed
-            return _Response.error(HTTPStatus.TOO_MANY_REQUESTS, str(e), retry=True)
-        except RuntimeError as e:  # stopping/stopped batcher: reject, 503
-            return _Response.error(HTTPStatus.SERVICE_UNAVAILABLE, str(e))
+            except QueueFull as e:  # batcher-level bound won the race; shed
+                return _Response.error(HTTPStatus.TOO_MANY_REQUESTS, str(e), retry=True)
+            except RuntimeError as e:  # stopping/stopped batcher: reject, 503
+                return _Response.error(HTTPStatus.SERVICE_UNAVAILABLE, str(e))
         awaitables = [self._bridge(loop, fut) for fut in futures]
 
         try:
@@ -687,6 +713,7 @@ class HdcHttpServer(AsyncHttpServer):
                 HTTPStatus.INTERNAL_SERVER_ERROR, f"{type(e).__name__}: {e}"
             )
 
+        write = _profiler.span("hdc.http.write").__enter__()  # closed once flushed
         t_write_start = time.perf_counter()
         for fut in futures:
             if fut.trace is not None:
@@ -704,7 +731,7 @@ class HdcHttpServer(AsyncHttpServer):
         # echo the effective id so a client that did not mint one can
         # still resolve its trace (`/v1/traces?id=`) after the fact
         response.extra_headers[protocol.HDR_REQUEST_ID] = rid
-        response.on_written = self._trace_writer(batcher, futures)
+        response.on_written = self._trace_writer(batcher, futures, write)
         return response
 
     # -- search (top-k scored retrieval, DESIGN.md §14) --------------------
@@ -730,65 +757,66 @@ class HdcHttpServer(AsyncHttpServer):
         cfg = batcher.engine.model.cfg
         n_features = cfg.n_features
 
-        content_type = request.header("content-type", protocol.CT_JSON)
-        content_type = content_type.split(";")[0].strip().lower()
-        single = False
-        try:
-            if content_type == protocol.CT_F32:
-                queries = protocol.decode_images(request.body, n_features)
-                k = protocol.parse_k(request.query.get("k", "1"))
-            elif content_type == protocol.CT_JSON:
-                queries, k, single = protocol.parse_search_json(
-                    json.loads(request.body or b"{}")
-                )
-            else:
+        with _profiler.span("hdc.http.decode"):
+            content_type = request.header("content-type", protocol.CT_JSON)
+            content_type = content_type.split(";")[0].strip().lower()
+            single = False
+            try:
+                if content_type == protocol.CT_F32:
+                    queries = protocol.decode_images(request.body, n_features)
+                    k = protocol.parse_k(request.query.get("k", "1"))
+                elif content_type == protocol.CT_JSON:
+                    queries, k, single = protocol.parse_search_json(
+                        json.loads(request.body or b"{}")
+                    )
+                else:
+                    return _Response.error(
+                        HTTPStatus.UNSUPPORTED_MEDIA_TYPE,
+                        f"unsupported content type {content_type!r}; "
+                        f"use {protocol.CT_JSON} or {protocol.CT_F32}",
+                    )
+                if queries.shape[1] != n_features:
+                    raise ValueError(
+                        f"model {name!r} takes {n_features} features per query, "
+                        f"got {queries.shape[1]}"
+                    )
+                if k > cfg.n_classes:
+                    raise ValueError(
+                        f"k={k} exceeds the {cfg.n_classes} rows in model "
+                        f"{name!r}'s store"
+                    )
+            except (ValueError, TypeError, json.JSONDecodeError) as e:
+                return _Response.error(HTTPStatus.BAD_REQUEST, str(e))
+
+            # -- admission: same bounded queue depth as predict ----------------
+            limit = batcher.max_depth
+            if limit is None:
+                limit = self.max_queue_depth
+            if limit is not None and batcher.queue_depth() + len(queries) > limit:
+                batcher.metrics.shed(len(queries))
                 return _Response.error(
-                    HTTPStatus.UNSUPPORTED_MEDIA_TYPE,
-                    f"unsupported content type {content_type!r}; "
-                    f"use {protocol.CT_JSON} or {protocol.CT_F32}",
+                    HTTPStatus.TOO_MANY_REQUESTS,
+                    f"model {name!r} overloaded: queue depth "
+                    f"{batcher.queue_depth()} + {len(queries)} exceeds {limit}",
+                    retry=True,
                 )
-            if queries.shape[1] != n_features:
-                raise ValueError(
-                    f"model {name!r} takes {n_features} features per query, "
-                    f"got {queries.shape[1]}"
-                )
-            if k > cfg.n_classes:
-                raise ValueError(
-                    f"k={k} exceeds the {cfg.n_classes} rows in model "
-                    f"{name!r}'s store"
-                )
-        except (ValueError, TypeError, json.JSONDecodeError) as e:
-            return _Response.error(HTTPStatus.BAD_REQUEST, str(e))
 
-        # -- admission: same bounded queue depth as predict ----------------
-        limit = batcher.max_depth
-        if limit is None:
-            limit = self.max_queue_depth
-        if limit is not None and batcher.queue_depth() + len(queries) > limit:
-            batcher.metrics.shed(len(queries))
-            return _Response.error(
-                HTTPStatus.TOO_MANY_REQUESTS,
-                f"model {name!r} overloaded: queue depth "
-                f"{batcher.queue_depth()} + {len(queries)} exceeds {limit}",
-                retry=True,
+            loop = asyncio.get_running_loop()
+            rid = adopt_request_id(
+                request.header(protocol.HDR_REQUEST_ID)
+            ) or new_request_id()
+            request_ids = (
+                [rid] if len(queries) == 1
+                else [f"{rid}/{i}" for i in range(len(queries))]
             )
-
-        loop = asyncio.get_running_loop()
-        rid = adopt_request_id(
-            request.header(protocol.HDR_REQUEST_ID)
-        ) or new_request_id()
-        request_ids = (
-            [rid] if len(queries) == 1
-            else [f"{rid}/{i}" for i in range(len(queries))]
-        )
-        try:
-            futures = batcher.submit_search_block(
-                queries, k, request_ids=request_ids, trace_owner=OWNER_TRANSPORT
-            )
-        except QueueFull as e:
-            return _Response.error(HTTPStatus.TOO_MANY_REQUESTS, str(e), retry=True)
-        except RuntimeError as e:  # stopping batcher, or fully-drained pool
-            return _Response.error(HTTPStatus.SERVICE_UNAVAILABLE, str(e))
+            try:
+                futures = batcher.submit_search_block(
+                    queries, k, request_ids=request_ids, trace_owner=OWNER_TRANSPORT
+                )
+            except QueueFull as e:
+                return _Response.error(HTTPStatus.TOO_MANY_REQUESTS, str(e), retry=True)
+            except RuntimeError as e:  # stopping batcher, or fully-drained pool
+                return _Response.error(HTTPStatus.SERVICE_UNAVAILABLE, str(e))
         awaitables = [self._bridge(loop, fut) for fut in futures]
 
         try:
@@ -810,6 +838,7 @@ class HdcHttpServer(AsyncHttpServer):
                 HTTPStatus.INTERNAL_SERVER_ERROR, f"{type(e).__name__}: {e}"
             )
 
+        write = _profiler.span("hdc.http.write").__enter__()  # closed once flushed
         t_write_start = time.perf_counter()
         for fut in futures:
             if fut.trace is not None:
@@ -841,16 +870,18 @@ class HdcHttpServer(AsyncHttpServer):
                 },
             )
         response.extra_headers[protocol.HDR_REQUEST_ID] = rid
-        response.on_written = self._trace_writer(batcher, futures)
+        response.on_written = self._trace_writer(batcher, futures, write)
         return response
 
-    def _trace_writer(self, batcher, futures) -> Callable[[], None]:
-        """Closure run after the response bytes are flushed: closes each
-        trace's write span and lands it in the shared ring — the trace's
-        e2e therefore covers queue -> device -> socket flush."""
+    def _trace_writer(self, batcher, futures, write) -> Callable[[], None]:
+        """Closure run after the response bytes are flushed: closes the
+        ``hdc.http.write`` span `write` and each trace's write span, and
+        lands the traces in the shared ring — the trace's e2e therefore
+        covers queue -> device -> socket flush."""
 
         def finish() -> None:
             t_end = time.perf_counter()
+            write.__exit__(None, None, None)
             traces = getattr(self.registry, "traces", None)
             for fut in futures:
                 trace = fut.trace

@@ -27,7 +27,7 @@ from repro.obs import (
     TraceBuffer,
     new_request_id,
     render_prometheus,
-    timed_block,
+    span,
 )
 from repro.obs.histogram import log_bounds
 from repro.serving import MicroBatcher, ModelRegistry, ServingEngine
@@ -298,12 +298,98 @@ def test_direct_batcher_traces_without_transport():
     assert snap["stages"]["device"]["count"] == 6
 
 
-def test_timed_block_measures_and_syncs():
-    with timed_block("t") as tb:
-        x = tb.sync(jnp.arange(8) * 2)
-        time.sleep(0.01)
-    assert tb.elapsed_s >= 0.01
-    np.testing.assert_array_equal(np.asarray(x), np.arange(8) * 2)
+def _traced(tmp_path, work):
+    """Events of a CPU `jax.profiler` trace around `work()`:
+    name -> [((plane, line index), start ns, end ns)]."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    events = {}
+    for path in tmp_path.rglob("*.xplane.pb"):
+        for plane in ProfileData.from_file(str(path)).planes:
+            # a line per thread; threads may share a line name
+            for i, line in enumerate(plane.lines):
+                for e in line.events:
+                    events.setdefault(e.name, []).append(
+                        ((plane.name, i), e.start_ns, e.end_ns))
+    return events
+
+
+def test_span_measures_and_lands_on_the_profiler_clock(tmp_path):
+    """`obs.span` keeps its wall time and writes a trace event of the
+    same name; the serving path's spans appear, and the engine's
+    host-to-device copy and result read nest inside its step."""
+    cfg = _cfg()
+    batcher = MicroBatcher(ServingEngine(_trained(cfg), batch_size=4))
+    out = {}
+
+    def work():
+        with span("hdc.test.block") as sp:
+            time.sleep(0.01)
+        out["elapsed"] = sp.elapsed_s
+        futs = [batcher.submit(img)
+                for img in RNG.uniform(0, 255, (3, cfg.n_features))]
+        batcher.step()
+        out["labels"] = [f.result(timeout=10.0) for f in futs]
+
+    events = _traced(tmp_path, work)
+    assert out["elapsed"] >= 0.01 and len(out["labels"]) == 3
+    (_, t0, t1), = events["hdc.test.block"]
+    assert (t1 - t0) * 1e-9 == pytest.approx(out["elapsed"], abs=2e-3)
+    (line, s0, s1), = events["hdc.engine.step"]
+    for inner in ("hdc.engine.put", "hdc.engine.read"):
+        (iline, a, b), = events[inner]
+        assert iline == line and s0 <= a <= b <= s1, inner
+    assert len(events["hdc.batcher.assemble"]) == 1
+
+
+def test_span_names_every_layer_of_a_served_request(stack, tmp_path):
+    """Through HTTP: decode and write on the loop thread, wait and
+    assemble on the drain thread, the engine step between them; and a
+    forced collection under the running server is a `python.gc` span."""
+    import gc
+
+    cfg = _cfg()
+    registry, server, client = stack(_trained(cfg))
+    image = RNG.uniform(0, 255, cfg.n_features)
+
+    def work():
+        for _ in range(2):  # the drain thread's wait between them is traced whole
+            client.predict("m", image)
+        gc.collect()
+
+    events = _traced(tmp_path, work)
+    for name in ("hdc.http.decode", "hdc.batcher.wait", "hdc.batcher.assemble",
+                 "hdc.engine.step", "hdc.engine.put", "hdc.engine.read",
+                 "hdc.http.write", "python.gc"):
+        assert name in events, name
+    (decode_line, _, decoded), _ = events["hdc.http.decode"]
+    (write_line, written, _), _ = events["hdc.http.write"]
+    (step_line, stepped, _), _ = events["hdc.engine.step"]
+    assert decode_line == write_line != step_line
+    assert decoded <= stepped <= written
+
+
+def test_gc_span_hook_lives_while_a_server_runs(stack):
+    import gc
+
+    from repro.obs import profiler
+
+    hook = profiler._gc_span
+    assert hook not in gc.callbacks
+    _, server, _ = stack(_trained(_cfg()))
+    _, other, _ = stack(_trained(_cfg()))
+    assert gc.callbacks.count(hook) == 1
+    server.stop()
+    assert gc.callbacks.count(hook) == 1  # the other server still runs
+    other.stop()
+    other.stop()  # idempotent: no second removal
+    assert hook not in gc.callbacks
 
 
 # ---------------------------------------------------------------------------

@@ -6,12 +6,16 @@ array's own — nor the scoped VMEM limit.  The TPU compiler is
 installed even where no chip is attached, so each kernel is compiled
 here at the paper's widths (H=784, C=10, D=8192, 16 levels) and at the
 store and shard sizes the system serves.  Nothing runs: these tests
-say a kernel compiles natively, not that it is right or fast.
+say a kernel compiles natively, not that it is right or fast.  Every
+kernel, main path or not, is also lowered once to check the name it
+carries into the device trace.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,3 +97,33 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in operands]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), "no native kernel in the program"
+
+
+# every Pallas kernel by the name it carries (``hdc_kernel`` in the custom
+# call's kernel_metadata, which the device trace's op text holds): the
+# main-path entries above, and the two kernels off the main path
+NAMED = {
+    "encode_bundle": KERNELS["encode_bundle_B64"],
+    "encode_bundle_dynamic": KERNELS["encode_bundle_dynamic_B64"],
+    "fit_bundle": KERNELS["fit_bundle_B256"],
+    "fit_bundle_dynamic": KERNELS["fit_bundle_dynamic_B256"],
+    "hamming_packed": KERNELS["hamming_packed_C10"],
+    "hamming_topk": KERNELS["hamming_topk_C10_k1"],
+    "encode_unary_mxu": (
+        lambda x, s: ops.encode_unary_mxu(x, s, 16, interpret=False),
+        [((64, H), jnp.int32), ((H, D), jnp.int32)],
+    ),
+    "bundle_binarize": (
+        lambda hv, y: ops.bundle_binarize(hv, y, C, interpret=False),
+        [((256, D), jnp.int32), ((256,), jnp.int32)],
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NAMED))
+def test_kernel_carries_its_name(one_chip, name):
+    fn, operands = NAMED[name]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in operands]
+    text = jax.jit(fn).lower(*args).as_text(dialect="hlo")
+    named = re.findall(r'kernel_metadata=\{\s*"hdc_kernel":"(\w+)"\s*\}', text)
+    assert named and set(named) == {name}, named
