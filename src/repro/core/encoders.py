@@ -180,10 +180,12 @@ def _uhd_blocked_fit_bundle(cfg, books, x_q, labels, *, d, point_offset):
 
 @register_fit_bundle("uhd", "pallas")
 def _uhd_pallas_fit_bundle(cfg, books, x_q, labels, *, d, point_offset):
-    """Fused Pallas encode+bundle+class-sum kernel."""
+    """Fused Pallas encode+bundle+class-sum kernel: the batch folded per
+    class and level, then contracted with the table on the MXU over its
+    `cfg.levels` threshold values."""
     from repro.kernels import ops  # local import: kernels are optional
 
-    return ops.fit_bundle(x_q, books["sobol"], labels, cfg.n_classes)
+    return ops.fit_bundle(x_q, books["sobol"], labels, cfg.n_classes, cfg.levels)
 
 
 @register_topk("uhd", "pallas")

@@ -240,23 +240,26 @@ class HDCModel:
         streaming state is donated between steps, so the (C, D)
         accumulator is updated in place instead of re-allocated per
         batch (this model's own buffers are untouched: the stream
-        starts from a fresh `reset` copy)."""
+        starts from fresh zeros, as `reset` gives)."""
         with span("hdc.fit.reset"):
-            model = self.reset()
+            sums, n_seen = _zeroed(self.class_sums, self.n_seen)
+        view = _stateless(self)  # once per stream, not per step
         for images, labels in batches:
-            # the step's host work: label check, stateless view, donated
-            # dispatch; it also holds the wait while the device's queue
-            # of programs is full
+            # the step's host work: label check and donated dispatch; it
+            # also holds the wait while the device's queue of programs
+            # is full
             with span("hdc.fit.step"):
-                model = model.partial_fit(images, labels, donate=True)
-        return model
+                labels = jnp.asarray(labels)
+                encoding.validate_labels(labels, self.cfg.n_classes)
+                sums, n_seen = _partial_fit_donated(
+                    view, sums, n_seen, jnp.asarray(images), labels
+                )
+        return self.replace(class_sums=sums, n_seen=n_seen)
 
     def reset(self) -> "HDCModel":
         """Drop accumulated class state (codebooks are kept)."""
-        return self.replace(
-            class_sums=jnp.zeros_like(self.class_sums),
-            n_seen=jnp.zeros_like(self.n_seen),
-        )
+        sums, n_seen = _zeroed(self.class_sums, self.n_seen)
+        return self.replace(class_sums=sums, n_seen=n_seen)
 
     def convert(self, encoder: str) -> "HDCModel":
         """Re-encoder this model within its family, keeping class state.
@@ -508,14 +511,18 @@ partial_fit = jax.jit(_partial_fit)
 partial_fit.__doc__ = "Accumulate one batch of bundled class sums into the model."
 
 
+@jax.jit
+def _zeroed(class_sums: jax.Array, n_seen: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Fresh training state shaped like the given one, in one dispatch."""
+    return jnp.zeros_like(class_sums), jnp.zeros_like(n_seen)
+
+
 def _stateless(model: HDCModel) -> HDCModel:
-    """The model with its mutable training state swapped for empty
-    placeholders — passed *un-donated* alongside the donated state so
-    the shared, read-only codebooks are never invalidated by donation."""
-    return model.replace(
-        class_sums=jnp.zeros((0,), jnp.int32),
-        n_seen=jnp.zeros((0,), _NSEEN_DTYPE),
-    )
+    """The model with its mutable training state swapped for None (no
+    leaves, so no device work) — passed *un-donated* alongside the
+    donated state so the shared, read-only codebooks are never
+    invalidated by donation."""
+    return model.replace(class_sums=None, n_seen=None)
 
 
 @functools.partial(jax.jit, donate_argnums=(1, 2))

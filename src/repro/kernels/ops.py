@@ -28,6 +28,7 @@ from repro.kernels.encode_bundle import (
     encode_bundle_pallas,
     fit_bundle_dynamic_pallas,
     fit_bundle_pallas,
+    fit_tiles,
 )
 from repro.kernels.encode_unary_mxu import encode_unary_mxu_pallas
 from repro.kernels.hamming_packed import hamming_packed_pallas, round_up as _round_up
@@ -145,39 +146,39 @@ def fit_bundle(
     sobol_q: jax.Array,
     labels: jax.Array,
     n_classes: int,
+    levels: int,
     *,
-    block_b: int = 8,
-    block_h: int = 128,
-    block_d: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused training step over a threshold table. (B,H),(H,D),(B,) -> (C,D).
 
-    Semantics = `ref.fit_bundle` (integer-exact class sums; the (B, D)
-    hypervector batch never exists).  Padded features contribute exactly
-    -1 per dim to every *real* example, so the per-class correction is
-    (hp - h) * count_c; padded batch rows and padded classes carry zero
-    one-hot weight and drop out.
+    Semantics = `ref.fit_bundle` for thresholds in [0, levels)
+    (integer-exact class sums; the (B, D) hypervector batch never
+    exists).  The kernel counts, per class, the (image, feature) pairs
+    at or above each threshold; a class of n_c images then sums to
+    2 * count - h * n_c.  Padded features match no level and padded
+    batch rows and classes carry zero one-hot weight, so neither
+    counts.  Tiles follow from B and D (`fit_tiles`).
     """
     if interpret is None:
         interpret = _interpret_default()
     b, h = x_q.shape
     d = sobol_q.shape[-1]
-    bp, hp, dp = _round_up(b, block_b), _round_up(h, block_h), _round_up(d, block_d)
-    cp = _round_up(max(n_classes, 8), 8)
+    block_b, block_d = fit_tiles(b, h, d)
+    bp, hp, dp = _round_up(b, block_b), _round_up(h, 128), _round_up(d, block_d)
+    cp = _round_up(max(n_classes, 16), 16)
     xp = jnp.pad(x_q.astype(jnp.int32), ((0, bp - b), (0, hp - h)), constant_values=-1)
     sp = jnp.pad(
-        sobol_q.astype(jnp.int32),
+        sobol_q,
         ((0, hp - h), (0, dp - d)),
-        constant_values=np.iinfo(np.int32).max,
+        constant_values=np.iinfo(sobol_q.dtype).max,
     )
     oh = _padded_class_onehot(labels, cp, bp)
-    out = fit_bundle_pallas(
-        xp, sp, oh, block_b=block_b, block_h=block_h, block_d=block_d,
-        interpret=interpret,
+    counts = fit_bundle_pallas(
+        xp, sp, oh.T, levels, block_b=block_b, block_d=block_d, interpret=interpret
     )
-    counts = oh[:, :n_classes].sum(axis=0, dtype=jnp.int32)
-    return out[:n_classes, :d] + (hp - h) * counts[:, None]
+    n_c = oh[:, :n_classes].sum(axis=0, dtype=jnp.int32)
+    return 2 * counts[:n_classes, :d] - h * n_c[:, None]
 
 
 def fit_bundle_dynamic(

@@ -61,28 +61,59 @@ def test_fused_datapaths_are_registered():
 
 @pytest.mark.parametrize("encoder,backend", FUSED)
 @pytest.mark.parametrize(
-    "d,skip,levels", [(96, 1, 16), (700, 5, 16), (128, 3, 256)]
+    "d,skip,levels,n,h",
+    [
+        pytest.param(96, 1, 16, 22, 24, id="96-1-16"),
+        pytest.param(700, 5, 16, 22, 24, id="700-5-16"),
+        pytest.param(128, 3, 256, 22, 24, id="128-3-256"),
+        # batches off the fit kernel's row tile: the fit benchmark's
+        # last step of an epoch (608 images), and a single image
+        pytest.param(96, 1, 16, 608, 24, id="96-1-16-b608"),
+        pytest.param(96, 1, 16, 1, 24, id="96-1-16-b1"),
+        # one shard of a four-chip D-sharded fit (d_local = 2048)
+        pytest.param(2048, 1, 16, 22, 24, id="2048-1-16"),
+        # the paper's MNIST width, H = 784 padded to 896
+        pytest.param(256, 1, 16, 22, 784, id="256-1-16-h784"),
+    ],
 )
-def test_fit_bundle_matches_encode_then_bundle_oracle(encoder, backend, d, skip, levels):
+def test_fit_bundle_matches_encode_then_bundle_oracle(
+    encoder, backend, d, skip, levels, n, h
+):
     """Acceptance: fused class sums bit-identical to the
-    encode-then-bundle_by_class oracle, across D % tile != 0 and nonzero
-    sobol_skip, for every fused datapath of both encoders."""
-    cfg = _cfg(d=d, sobol_skip=skip, levels=levels, encoder=encoder, backend=backend)
+    encode-then-bundle_by_class oracle, across D % tile != 0, nonzero
+    sobol_skip, ragged and single-image batches and padded H, for every
+    fused datapath of both encoders.  The batch holds an all-dark and
+    an all-bright image, set on the quantized levels (x_q at 0 and at
+    `levels`), and at 16 levels the thresholds span 0 to 15: the edge
+    levels of the MXU contraction."""
+    cfg = _cfg(
+        d=d, sobol_skip=skip, levels=levels, n_features=h, encoder=encoder,
+        backend=backend,
+    )
     model = HDCModel.create(cfg)
-    x, y = _data(cfg, n=22)
-    x_q = encoding.quantize_images(jnp.asarray(x), cfg.levels, cfg.max_intensity)
-    # oracle: the encoder's reference oracle datapath, then exact bundling
     enc = get_encoder(encoder)
-    hvs = model.encode(x, backend=enc.reference_backend)
+    x, y = _data(cfg, n=n)
+    x_q = encoding.quantize_images(jnp.asarray(x), cfg.levels, cfg.max_intensity)
+    x_q = x_q.at[0].set(0).at[-1].set(levels)  # one image: all bright
+    assert int(x_q.min()) == (0 if n > 1 else levels) and int(x_q.max()) == levels
+    if levels == 16:  # 128 points leave some of 256 levels unused
+        table = get_encoder("uhd").build_codebooks(cfg)["sobol"]
+        assert int(table.min()) == 0 and int(table.max()) == levels - 1
+    # oracle: the encoder's reference oracle datapath, then exact bundling
+    hvs = enc.encode(cfg, model.codebooks, x_q, backend=enc.reference_backend)
     oracle = encoding.bundle_by_class(hvs, y, cfg.n_classes)
     fused = enc.fit_bundle(cfg, model.codebooks, x_q, y, backend=backend)
     np.testing.assert_array_equal(
         np.asarray(fused), np.asarray(oracle),
-        err_msg=f"{encoder}/{backend} d={d} skip={skip} levels={levels}",
+        err_msg=f"{encoder}/{backend} d={d} skip={skip} levels={levels} n={n} h={h}",
     )
-    # and through the public training entry point
+    # and through the public training entry point, on the raw images
+    hvs = model.encode(x, backend=enc.reference_backend)
     trained = model.fit(x, y)
-    np.testing.assert_array_equal(np.asarray(trained.class_sums), np.asarray(oracle))
+    np.testing.assert_array_equal(
+        np.asarray(trained.class_sums),
+        np.asarray(encoding.bundle_by_class(hvs, y, cfg.n_classes)),
+    )
 
 
 def test_partial_fit_routes_through_fused_datapath(monkeypatch):

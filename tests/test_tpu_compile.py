@@ -24,7 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 
-H, C, D = 784, 10, 8192
+H, C, D, L = 784, 10, 8192, 16
 W = D // 32
 
 
@@ -63,8 +63,27 @@ KERNELS = {
         [((64, H), jnp.int32), ((H, 32), jnp.uint8)],
     ),
     "fit_bundle_B256": (
-        lambda x, s, y: ops.fit_bundle(x, s, y, C, interpret=False),
+        lambda x, s, y: ops.fit_bundle(x, s, y, C, L, interpret=False),
         [((256, H), jnp.int32), ((H, D), jnp.int8), ((256,), jnp.int32)],
+    ),
+    # the fit benchmark's steps: 2048 images, and the epoch's last 608
+    "fit_bundle_B2048": (
+        lambda x, s, y: ops.fit_bundle(x, s, y, C, L, interpret=False),
+        [((2048, H), jnp.int32), ((H, D), jnp.int8), ((2048,), jnp.int32)],
+    ),
+    "fit_bundle_B608": (
+        lambda x, s, y: ops.fit_bundle(x, s, y, C, L, interpret=False),
+        [((608, H), jnp.int32), ((H, D), jnp.int8), ((608,), jnp.int32)],
+    ),
+    # 3072 features: the tiles shrink so the whole feature axis fits VMEM
+    "fit_bundle_H3072": (
+        lambda x, s, y: ops.fit_bundle(x, s, y, C, L, interpret=False),
+        [((2048, 3072), jnp.int32), ((3072, D), jnp.int8), ((2048,), jnp.int32)],
+    ),
+    # 256 levels: sixteen grid rows of 16 levels each, over the int32 table
+    "fit_bundle_B256_L256": (
+        lambda x, s, y: ops.fit_bundle(x, s, y, C, 256, interpret=False),
+        [((256, H), jnp.int32), ((H, D), jnp.int32), ((256,), jnp.int32)],
     ),
     "fit_bundle_dynamic_B256": (
         lambda x, dr, y: ops.fit_bundle_dynamic(x, dr, y, C, D, interpret=False),
