@@ -9,14 +9,10 @@ whole run of the cell and judged by the cell's own check.
 Run it on the chip, at the cell's own sizes, by hand: each run must
 come out not correct (the benchmark's runs never run it).  It prints
 one JSON line per seed with ``correct`` and the checks.  What stands in
-the program's place is the entry the cell's window drives:
-
-  * fit: the jitted fit step (`hdc_model._partial_fit_donated`) adds
-    the lower reference's class sums of its batch;
-  * search: `hdc_model.search_packed` returns the lower reference's
-    top-k of its queries over the store;
-  * http_open: `ServingEngine.predict` returns the lower reference's
-    labels, from class words it trained on the served model's set.
+the program's place is the entry the cell's window drives, named by the
+cell's driver (``control(cfg, traffic, seed)`` in
+``bench/drivers/<driver>.py``, a list of (owner, attribute, stand-in)):
+the fit step, `search_packed`, `ServingEngine.predict`.
 
 The program's own lowering of each entry still answers the preflight's
 look for compiled kernels; only the calls are replaced.
@@ -33,8 +29,8 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from bench import harness, inputs  # noqa: E402
-from bench.reference import Reference, topk_words  # noqa: E402
+from bench import harness  # noqa: E402
+from bench.reference import Reference  # noqa: E402
 
 
 def lower_reference(cfg: dict, seed: int) -> Reference:
@@ -55,53 +51,6 @@ class Stand:
         return self.real.lower(*args, **kwargs)
 
 
-def _fit(cfg: dict, traffic: dict, seed: int):
-    import jax.numpy as jnp
-
-    from repro.core import hdc_model
-
-    low = lower_reference(cfg, seed)
-
-    def step(stateless, sums, n_seen, images, labels):
-        n = jnp.uint32(labels.shape[0])
-        lo = n_seen[1] + n
-        hi = n_seen[0] + (lo < n_seen[1]).astype(n_seen.dtype)
-        return sums + low.class_sums(images, labels), jnp.stack([hi, lo])
-
-    real = hdc_model._partial_fit_donated
-    return [(hdc_model, "_partial_fit_donated", Stand(real, step))]
-
-
-def _search(cfg: dict, traffic: dict, seed: int):
-    from repro.core import hdc_model
-
-    low = lower_reference(cfg, seed)
-
-    def search(model, images, rows, *, k, impl):
-        return topk_words(low.query_words(images), rows, k)
-
-    return [(hdc_model, "search_packed", Stand(hdc_model.search_packed, search))]
-
-
-def _predict(cfg: dict, traffic: dict, seed: int):
-    import jax.numpy as jnp
-
-    from repro.serving import ServingEngine
-
-    low = lower_reference(cfg, seed)
-    x, y = inputs.device_dataset(seed, traffic["n_train"], cfg["n_features"],
-                                 cfg["n_classes"])
-    words = low.pack(low.class_sums(x, y))
-
-    def predict(engine, images):
-        return low.labels(jnp.asarray(images, jnp.float32), words)
-
-    return [(ServingEngine, "predict", predict)]
-
-
-SWAPS = {"fit": _fit, "search": _search, "http_open": _predict}
-
-
 @contextlib.contextmanager
 def swapped(workload: str, seed: int, *, root: Path = harness.ROOT,
             bench: Path = harness.BENCH, overrides: dict | None = None):
@@ -109,7 +58,9 @@ def swapped(workload: str, seed: int, *, root: Path = harness.ROOT,
     _, _, cfg, traffic = harness.resolve(workload, root, bench, overrides)
     if str(root / "src") not in sys.path:
         sys.path.insert(0, str(root / "src"))
-    swaps = SWAPS[traffic["driver"]](cfg, traffic, seed)
+    driver = harness.load_module(harness.driver_file(bench, traffic["driver"]),
+                                 f"bench_driver_{traffic['driver']}")
+    swaps = driver.control(cfg, traffic, seed)
     saved = [(owner, name, getattr(owner, name)) for owner, name, _ in swaps]
     try:
         for owner, name, value in swaps:

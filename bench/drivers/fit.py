@@ -7,6 +7,8 @@ step takes the remainder), ``trace_seconds``.
 End-to-end: ``fit_images_per_s`` = images folded into class sums over
 the window (whole epochs).  Checked: every epoch's class sums and
 example count against the reference's class sums of the same set.
+Control: the jitted fit step adds the lower reference's class sums of
+its batch.
 """
 
 from __future__ import annotations
@@ -86,3 +88,21 @@ def check(run):
         Check("class_sum_entries_differing", float(sums_off), 0.0),
         Check("examples_miscounted", float(count_off), 0.0),
     ]
+
+
+def control(cfg: dict, traffic: dict, seed: int):
+    import jax.numpy as jnp
+
+    from bench.control import Stand, lower_reference
+    from repro.core import hdc_model
+
+    low = lower_reference(cfg, seed)
+
+    def step(stateless, sums, n_seen, images, labels):
+        n = jnp.uint32(labels.shape[0])
+        lo = n_seen[1] + n
+        hi = n_seen[0] + (lo < n_seen[1]).astype(n_seen.dtype)
+        return sums + low.class_sums(images, labels), jnp.stack([hi, lo])
+
+    real = hdc_model._partial_fit_donated
+    return [(hdc_model, "_partial_fit_donated", Stand(real, step))]

@@ -1,16 +1,38 @@
-"""Set-up, window and check of the HTTP serving driver.
+"""Driver ``http``: raw-binary ``:predict`` requests over HTTP to one
+`HdcHttpServer`, sent by the load generator (`bench/loadgen.py`) in its
+own process.
+
+Traffic keys: ``loop``, "open" (requests due on a seeded schedule:
+``arrival`` "poisson" at ``rate_per_s``, each timed from when it was
+due) or "closed" (each of the ``connections`` sends its next request as
+soon as its last answer arrives, with no think time, until the window's
+time is up); ``images_per_request`` (one block, served by one device
+step when it equals ``batch_size``), ``image_pool`` distinct images
+cycled through, ``connections``, the deployment (``batch_size``,
+``max_delay_ms``, ``max_queue_depth``, optionally ``replicas`` and
+``placement``), the served model's training set (``n_train``,
+``fit_batch``), ``trace_seconds``.
 
 Set-up trains the served model from the seed (`fit_batches` over a
 device-made training set), saves it, registers it through
-`ModelRegistry.register_checkpoint` (one engine), starts an
-`HdcHttpServer` on a local port, and starts the load generator (`bench/loadgen.py`) in its own process,
-which warms every connection.  The window opens when the generator is
-told to go and closes when it has every answer.  The program's stage
-histograms and counters are read before and after, so the per-layer
-metrics see the window alone.
+`ModelRegistry.register_checkpoint` (one unpinned engine by default, a
+`ReplicaPool` for more replicas), starts the server on a local port,
+and starts the load generator, which warms every connection.  The
+window opens when the generator is told to go and closes when it has
+every answer.  The program's fleet-merged stage histograms and counters
+(`ModelRegistry.metrics_state`) and a pool's per-replica dispatch counts
+(`describe_entry`) are read before and after, so the per-layer metrics
+see the window alone.
+
+End-to-end: ``predict_p99_ms``, every request timed from when it was
+due, and ``predict_images_per_s``, the images answered with status 200
+over the window; a cell reports the one `BENCHMARK.json` gives it.
 
 Checked: every label returned over the wire against the reference's
-label for that image, and no request left without an answer.
+label for that image; no request left without an answer; no request
+answered with any status but 200 (shed, refused or failed).  Control:
+`ServingEngine.predict` returns the lower reference's labels, from
+class words it trained on the served model's set.
 """
 
 from __future__ import annotations
@@ -29,7 +51,7 @@ from bench import inputs, preflight
 from bench.spans import Hist
 
 MODEL = "bench"
-LOADGEN = Path(__file__).resolve().parent / "loadgen.py"
+LOADGEN = Path(__file__).resolve().parents[1] / "loadgen.py"
 FAILED_LATENCY_MS = 60_000.0  # a request without an answer misses every limit
 
 
@@ -58,7 +80,8 @@ def setup(run) -> None:
     s["registry"] = registry
     engine = registry.register_checkpoint(
         MODEL, Path(s["workdir"]) / "ckpt", step=0, batch_size=t["batch_size"],
-        impl="auto", max_delay_ms=t["max_delay_ms"], start=True,
+        impl="auto", replicas=t.get("replicas", 1),
+        placement=t.get("placement", "auto"), max_delay_ms=t["max_delay_ms"], start=True,
     ).engine
     preflight.native(run, hdc_model.predict_packed.lower(
         engine.model, jnp.zeros((t["batch_size"], c["n_features"]), jnp.float32),
@@ -73,6 +96,7 @@ def setup(run) -> None:
         "seconds": run.seconds, "traffic": t, "pool": t["image_pool"],
         "n_features": c["n_features"], "images_per_request": t["images_per_request"],
         "connections": t["connections"], "timeout_s": FAILED_LATENCY_MS / 1e3,
+        "loop": t["loop"],
         "out": str(Path(s["workdir"]) / "loadgen.npz"),
     }
     spec_path = Path(s["workdir"]) / "loadgen.json"
@@ -86,8 +110,11 @@ def setup(run) -> None:
     run.mark("server")
 
 
-def _snapshot(run) -> dict:
-    return run.state["registry"].batcher(MODEL).metrics.state()
+def _snapshot(run) -> tuple[dict, list[int] | None]:
+    """Fleet-merged serving metrics, and a pool's blocks per replica."""
+    registry = run.state["registry"]
+    return (registry.metrics_state()[MODEL]["serving"],
+            registry.describe_entry(MODEL).get("n_dispatched"))
 
 
 def window(run) -> dict:
@@ -106,8 +133,9 @@ def window(run) -> dict:
         raise RuntimeError(f"load generator failed (exit {child.returncode})")
     after = _snapshot(run)
     lg = json.loads(summary)
-    run.note(f"load generator: {lg['requests']} requests, {lg['ok']} answered 200; sent "
-             f"late by p50 {lg['late_p50_ms']:.3f} ms, p99 {lg['late_p99_ms']:.3f} ms, "
+    run.note(f"load generator: {lg['requests']} requests, {lg['ok']} answered 200, "
+             f"at most {lg['outstanding_max']} outstanding; sent late by p50 "
+             f"{lg['late_p50_ms']:.3f} ms, p99 {lg['late_p99_ms']:.3f} ms, "
              f"max {lg['late_max_ms']:.3f} ms")
 
     res = dict(np.load(s["loadgen_out"]))
@@ -122,9 +150,13 @@ def window(run) -> dict:
     run.e2e["predict_images_per_s"] = images / (float(np.nanmax(res["done"]))
                                                 - float(res["t0"]))
 
-    b, a = before, after
+    (b, b_disp), (a, a_disp) = before, after
     run.counters = {k: a["counters"][k] - b["counters"][k]
                     for k in ("n_batches", "n_slots", "n_padded", "n_requests", "n_shed")}
+    if a_disp is not None:
+        run.counters["n_dispatched"] = [x - y for x, y in zip(a_disp, b_disp)]
+        run.note(f"blocks dispatched per replica in the window: "
+                 f"{run.counters['n_dispatched']}")
     run.spans = {name: Hist.delta(b["stages"].get(name), h)
                  for name, h in a["stages"].items()}
     run.work.update(window_s=w.seconds)
@@ -170,10 +202,29 @@ def check(run):
     want_rows = want[(res["block"][:, None] * per + np.arange(per)[None, :])]
     differing = int((res["labels"][ok] != want_rows[ok]).sum())
     never = int((res["status"] == -1).sum())
+    not_ok = int((res["status"] != 200).sum())
     return [
         Check("labels_differing", float(differing), 0.0),
         Check("requests_never_answered", float(never), 0.0),
+        Check("requests_not_ok", float(not_ok), 0.0),
     ]
+
+
+def control(cfg: dict, traffic: dict, seed: int):
+    import jax.numpy as jnp
+
+    from bench.control import lower_reference
+    from repro.serving import ServingEngine
+
+    low = lower_reference(cfg, seed)
+    x, y = inputs.device_dataset(seed, traffic["n_train"], cfg["n_features"],
+                                 cfg["n_classes"])
+    words = low.pack(low.class_sums(x, y))
+
+    def predict(engine, images):
+        return low.labels(jnp.asarray(images, jnp.float32), words)
+
+    return [(ServingEngine, "predict", predict)]
 
 
 def latency_ms(res: dict) -> np.ndarray:

@@ -19,7 +19,8 @@ the reference (block 0 and a sample drawn from the seed; every call of
 those blocks is compared), ``trace_seconds``.
 
 End-to-end: ``search_queries_per_s`` = queries answered over the
-window.
+window.  Control: `hdc_model.search_packed` returns the lower
+reference's top-k of its queries over the store.
 """
 
 from __future__ import annotations
@@ -138,3 +139,16 @@ def check(run):
         Check("topk_entries_differing", float(differing), 0.0),
         Check("planted_rows_missed", float(planted_missed), 0.0),
     ]
+
+
+def control(cfg: dict, traffic: dict, seed: int):
+    from bench.control import Stand, lower_reference
+    from bench.reference import topk_words
+    from repro.core import hdc_model
+
+    low = lower_reference(cfg, seed)
+
+    def search(model, images, rows, *, k, impl):
+        return topk_words(low.query_words(images), rows, k)
+
+    return [(hdc_model, "search_packed", Stand(hdc_model.search_packed, search))]

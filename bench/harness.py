@@ -7,7 +7,7 @@ gives it:
   * ``bench/configs/<config>.json``   the configuration's sizes;
   * ``bench/traffic/<traffic>.json``  the traffic mix, naming its driver;
   * ``bench/drivers/<driver>.py``     set-up, window and check of a kind
-    of traffic (fit, search, http_open);
+    of traffic (fit, search, http);
   * ``bench/metrics/<metric>.py``     the reader of one per-layer metric.
 
 A run: refuse without the chips the cell asks for; set up (inputs and
@@ -410,6 +410,8 @@ def run_cell(*, workload: str, seed: int, seconds: float, trace: bool,
         device["busy_s"] = red.busy_mean_s(used)
         device["window_s"] = red.window_s
         breakdown = {"device_ops": red.top_ops(10), "idle_gaps": red.idle_gaps(used, 10)}
+        run.note("device busy per chip in the traced window (s): "
+                 + ", ".join(f"{d}={red.busy_s(d):.6f}" for d in used))
         metrics = read_per_layer(run)
         run.note("end-to-end readings of this traced run (not metrics): "
                  + ", ".join(f"{k}={v}" for k, v in run.e2e.items()))

@@ -6,14 +6,20 @@ It never imports JAX, so the chip stays with the server's process.  It
 makes its images from the run's seed (`bench.inputs`, numpy only),
 opens its keep-alive connections, sends one warm-up request on each,
 prints ``ready`` and waits for ``go`` on standard input.  Then it runs
-an open loop, writes every request's times, status and labels to
-``spec["out"]`` (``.npz``), and prints one JSON summary line.
+its loop (``spec["loop"]``), writes every request's times, status,
+block, connection and labels to ``spec["out"]`` (``.npz``), and prints
+one JSON summary line.
 
-Requests are due on the schedule drawn from the seed
+``open``: requests are due on the schedule drawn from the seed
 (`inputs.schedule`), whatever the server does.  Each request's latency
 runs from when it was due, not from when it was sent, so a stall is
 charged to every request due during it; how late the generator sent is
 reported beside it.
+
+``closed``: each connection sends its next request the moment its last
+answer arrives (a request is due then), until ``spec["seconds"]`` have
+passed; the requests still out are waited for.  At most one request is
+out on a connection, so at most ``connections`` in all.
 
 The protocol is the server's raw binary hot path: a POST of C-order
 little-endian float32 rows (``application/x-hdc-f32``) answered by
@@ -23,6 +29,7 @@ int32 labels (``application/x-hdc-i32``).
 from __future__ import annotations
 
 import asyncio
+import itertools
 import json
 import sys
 import time
@@ -41,8 +48,8 @@ CT_I32 = "application/x-hdc-i32"
 class Conn:
     """One keep-alive HTTP/1.1 connection."""
 
-    def __init__(self, host: str, port: int, path: str):
-        self.host, self.port, self.path = host, port, path
+    def __init__(self, host: str, port: int, path: str, index: int):
+        self.host, self.port, self.path, self.index = host, port, path, index
         self.reader = self.writer = None
 
     async def open(self) -> "Conn":
@@ -86,18 +93,37 @@ def _bodies(spec: dict) -> tuple[list[bytes], int]:
 
 
 class Recorder:
+    FIELDS = ("due", "sent", "done", "status", "block", "conn", "labels")
+
     def __init__(self, n: int, per: int):
+        self.n = n
         self.due = np.full(n, np.nan)
         self.sent = np.full(n, np.nan)
         self.done = np.full(n, np.nan)
         self.status = np.zeros(n, np.int32)
         self.block = np.zeros(n, np.int32)
+        self.conn = np.zeros(n, np.int32)
         self.labels = np.full((n, per), -1, np.int32)
+
+    def take(self) -> int:
+        """Index of one more request, growing the arrays as needed."""
+        i = self.n
+        self.n += 1
+        if i == len(self.due):
+            grown = Recorder(max(1, 2 * i), self.labels.shape[1])
+            for name in self.FIELDS:
+                getattr(grown, name)[:i] = getattr(self, name)
+                setattr(self, name, getattr(grown, name))
+        return i
+
+    def arrays(self) -> dict:
+        return {name: getattr(self, name)[: self.n] for name in self.FIELDS}
 
     async def one(self, i: int, conn: Conn, spec: dict, body: bytes, block: int,
                   per: int) -> Conn:
         """Send request i on `conn`; returns a usable connection."""
         self.block[i] = block
+        self.conn[i] = conn.index
         self.sent[i] = time.perf_counter()
         try:
             status, payload = await asyncio.wait_for(conn.post(body), spec["timeout_s"])
@@ -105,7 +131,7 @@ class Recorder:
             self.done[i] = time.perf_counter()
             self.status[i] = -1
             conn.close()
-            return await Conn(conn.host, conn.port, conn.path).open()
+            return await Conn(conn.host, conn.port, conn.path, conn.index).open()
         self.done[i] = time.perf_counter()
         self.status[i] = status
         if status == 200 and len(payload) == 4 * per:
@@ -137,11 +163,41 @@ async def _open_loop(spec, conns, bodies, per) -> tuple[Recorder, float]:
     return rec, t0
 
 
+async def _closed_loop(spec, conns, bodies, per) -> tuple[Recorder, float]:
+    rec = Recorder(0, per)
+    count = itertools.count()
+    t0 = time.perf_counter()
+    t_end = t0 + float(spec["seconds"])
+
+    async def client(conn: Conn) -> Conn:
+        while (now := time.perf_counter()) < t_end:
+            i = rec.take()
+            b = next(count) % len(bodies)
+            rec.due[i] = now
+            conn = await rec.one(i, conn, spec, bodies[b], b, per)
+        return conn
+
+    conns[:] = await asyncio.gather(*(client(c) for c in conns))
+    return rec, t0
+
+
+LOOPS = {"open": _open_loop, "closed": _closed_loop}
+
+
+def outstanding_max(sent: np.ndarray, done: np.ndarray) -> int:
+    """Most requests out at once (an answer frees its slot before a send
+    at the same instant takes it)."""
+    times = np.concatenate([done, sent])
+    steps = np.concatenate([-np.ones(len(done)), np.ones(len(sent))])
+    order = np.lexsort((steps, times))
+    return int(np.cumsum(steps[order]).max()) if len(times) else 0
+
+
 async def main_async(spec: dict) -> dict:
     bodies, per = _bodies(spec)
     path = f"/v1/models/{spec['model']}:predict"
     n_conns = int(spec["connections"])
-    conns = [await Conn(spec["host"], spec["port"], path).open() for _ in range(n_conns)]
+    conns = [await Conn(spec["host"], spec["port"], path, j).open() for j in range(n_conns)]
     for j, c in enumerate(conns):  # warm every connection and the server path
         status, _ = await c.post(bodies[j % len(bodies)])
         if status != 200:
@@ -150,15 +206,16 @@ async def main_async(spec: dict) -> dict:
     line = await asyncio.get_running_loop().run_in_executor(None, sys.stdin.readline)
     if line.strip() != "go":
         raise RuntimeError(f"expected 'go', got {line!r}")
-    rec, t0 = await _open_loop(spec, conns, bodies, per)
+    rec, t0 = await LOOPS[spec["loop"]](spec, conns, bodies, per)
     for c in conns:
         c.close()
-    np.savez(spec["out"], due=rec.due, sent=rec.sent, done=rec.done,
-             status=rec.status, block=rec.block, labels=rec.labels, t0=t0)
-    late = (rec.sent - rec.due) * 1e3
+    res = rec.arrays()
+    np.savez(spec["out"], t0=t0, **res)
+    late = (res["sent"] - res["due"]) * 1e3
     return {
-        "requests": int(len(rec.status)),
-        "ok": int((rec.status == 200).sum()),
+        "requests": int(len(res["status"])),
+        "ok": int((res["status"] == 200).sum()),
+        "outstanding_max": outstanding_max(res["sent"], res["done"]),
         "late_p50_ms": float(np.percentile(late, 50)) if late.size else 0.0,
         "late_p99_ms": float(np.percentile(late, 99)) if late.size else 0.0,
         "late_max_ms": float(late.max()) if late.size else 0.0,

@@ -23,21 +23,23 @@ import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from bench import harness, serve  # noqa: E402
+from bench import harness  # noqa: E402
 
 
 def one_rate(workload: str, rate: float, seconds: float, seed: int) -> dict:
     benchmark, cell, cfg, traffic = harness.resolve(workload)
     traffic["rate_per_s"] = rate
+    driver = harness.load_module(harness.driver_file(harness.BENCH, traffic["driver"]),
+                                f"bench_driver_{traffic['driver']}")
     run = harness.Run(benchmark=benchmark, cell=cell, cfg=cfg, traffic=traffic,
                       seed=seed, seconds=seconds, trace=False)
     harness.describe_device(run, require_tpu=True)
     try:
-        serve.setup(run)
-        res = serve.window(run)
+        driver.setup(run)
+        res = driver.window(run)
     finally:
-        serve.release(run)
-    lat = serve.latency_ms(res)
+        driver.release(run)
+    lat = driver.latency_ms(res)
     n = len(lat)
     first, last = lat[: n // 5], lat[-n // 5 :]
     out = {

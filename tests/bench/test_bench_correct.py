@@ -6,24 +6,19 @@ Control: the lower-precision reference in the timed entry's place
 (`bench/control.py`, the same swap the chip runs use).  Faults: a fit
 step that returns its state unchanged; a fit step that leaves out half
 of its batch (counting it all); an answer altered where it is produced
-(a search result, a label).  Every cell runs on one chip, so the
+(a search result, a label).  No cell exchanges anything between
+chips (the pool cell's router hands each block to one replica), so the
 exchange fault has no place to be planted.
 """
 
 import pytest
 
-from bench_tiny import TINY, root_with_http
-
-SEED = 2**40 + 3
+from bench_tiny import TINY, root_with_unlisted
 
 
 @pytest.mark.parametrize("workload", sorted(TINY))
 def test_control_fails_the_cells_comparison(workload, run_tiny, tmp_path):
-    from bench import control
-
-    root = root_with_http(tmp_path)
-    with control.swapped(workload, SEED, root=root, overrides=TINY[workload]):
-        out = run_tiny(workload, seed=SEED, root=root)
+    out = run_tiny(workload, root=root_with_unlisted(tmp_path), control=True)
     assert not out["correct"]
     first = next(iter(out["checks"].values()))
     assert first["value"] > first["limit"], out["checks"]
@@ -80,7 +75,8 @@ def test_search_answer_altered(run_tiny, monkeypatch):
     assert out["checks"]["topk_entries_differing"]["value"] > 0
 
 
-def test_label_altered_where_it_is_produced(run_tiny, monkeypatch, tmp_path):
+@pytest.mark.parametrize("workload", ["predict_dyn_http_poisson", "predict_dyn_pool4_blocks"])
+def test_label_altered_where_it_is_produced(workload, run_tiny, monkeypatch, tmp_path):
     from repro.serving import ServingEngine
 
     real = ServingEngine.predict
@@ -91,6 +87,29 @@ def test_label_altered_where_it_is_produced(run_tiny, monkeypatch, tmp_path):
         return labels
 
     monkeypatch.setattr(ServingEngine, "predict", altered)
-    out = run_tiny("predict_dyn_http_poisson", root=root_with_http(tmp_path))
+    out = run_tiny(workload, root=root_with_unlisted(tmp_path), child=False)
     assert not out["correct"]
     assert out["checks"]["labels_differing"]["value"] > 0
+
+
+@pytest.mark.parametrize("workload", ["predict_dyn_http_poisson", "predict_dyn_pool4_blocks"])
+def test_request_shed_where_it_is_admitted(workload, run_tiny, monkeypatch, tmp_path):
+    import itertools
+
+    from repro.serving import batcher
+
+    real = batcher.MicroBatcher.submit_block
+    calls = itertools.count()
+
+    def shedding(self, images, **kw):
+        n = next(calls)
+        if n >= 8 and n % 7 == 0:  # past the warm-up's 8 requests, every 7th
+            raise batcher.QueueFull("shed by the test")
+        return real(self, images, **kw)
+
+    monkeypatch.setattr(batcher.MicroBatcher, "submit_block", shedding)
+    out = run_tiny(workload, root=root_with_unlisted(tmp_path), child=False)
+    assert not out["correct"]
+    assert out["checks"]["requests_not_ok"]["value"] > 0
+    assert out["checks"]["labels_differing"]["value"] == 0
+    assert out["checks"]["requests_never_answered"]["value"] == 0

@@ -1,6 +1,7 @@
-"""The load generator: seeded schedules and the open loop's due-time
-origin (a stall is charged to every request due during it), against a
-stub HTTP server on a local port."""
+"""The load generator: seeded schedules, the open loop's due-time
+origin (a stall is charged to every request due during it) and the
+closed loop's outstanding requests, against a stub HTTP server on a
+local port."""
 
 import asyncio
 import json
@@ -27,11 +28,14 @@ def test_poisson_schedule_is_fixed_work_per_seed():
 
 
 class StubServer:
-    """Answers every :predict with int32 zeros; holds every answer that
-    comes due inside [stall_from, stall_to) until stall_to."""
+    """Answers every :predict with int32 zeros after `delay` seconds;
+    holds every answer that comes due inside [stall_from, stall_to) until
+    stall_to; counts the requests it holds at once."""
 
-    def __init__(self):
+    def __init__(self, delay: float = 0.0):
         self.stall_from = self.stall_to = float("inf")
+        self.delay = delay
+        self.held = self.held_max = 0
         self.loop = asyncio.new_event_loop()
         self.thread = threading.Thread(target=self.loop.run_forever, daemon=True)
         self.thread.start()
@@ -50,11 +54,15 @@ class StubServer:
                 if name.lower() == "content-length":
                     length = int(value)
             body = await reader.readexactly(length)
+            self.held += 1
+            self.held_max = max(self.held_max, self.held)
+            await asyncio.sleep(self.delay)
             now = time.perf_counter()
             if self.stall_from <= now < self.stall_to:
                 await asyncio.sleep(self.stall_to - now)
             n = len(body) // (4 * 16)
             payload = np.zeros(n, "<i4").tobytes()
+            self.held -= 1
             writer.write(b"HTTP/1.1 200 OK\r\nContent-Type: application/x-hdc-i32\r\n"
                          + f"Content-Length: {len(payload)}\r\n\r\n".encode() + payload)
             await writer.drain()
@@ -70,7 +78,7 @@ def _spec(tmp_path, port, **kw):
     spec = {"host": "127.0.0.1", "port": port, "model": "m", "seed": 3,
             "seconds": 1.2, "traffic": {"arrival": "poisson", "rate_per_s": 200.0},
             "pool": 32, "n_features": 16, "images_per_request": 1, "connections": 2,
-            "timeout_s": 30.0, "out": str(tmp_path / "out.npz")}
+            "timeout_s": 30.0, "loop": "open", "out": str(tmp_path / "out.npz")}
     spec.update(kw)
     path = tmp_path / "spec.json"
     path.write_text(json.dumps(spec))
@@ -119,3 +127,29 @@ def test_open_loop_charges_a_stall_to_every_request_due_in_it(tmp_path):
     # that lateness is inside the latency, not hidden by a send-time origin
     late = res["sent"] - res["due"]
     assert late[during].max() > 0.1 and summary["late_max_ms"] > 100
+
+
+def test_closed_loop_keeps_every_connection_busy_and_answers_all(tmp_path):
+    server = StubServer(delay=0.01)
+    try:
+        summary = _drive(_spec(tmp_path, server.port, loop="closed", seconds=0.6,
+                               traffic={}, pool=64, images_per_request=8,
+                               connections=6))
+    finally:
+        server.close()
+    res = np.load(tmp_path / "out.npz")
+    n = summary["requests"]
+    assert summary["ok"] == n and (res["status"] == 200).all()
+    assert (res["labels"] == 0).all() and len(res["labels"]) == n
+    # exactly `connections` out at once, as the server saw it too
+    assert summary["outstanding_max"] == 6 and server.held_max == 6
+    assert n >= 6 * 0.6 / 0.011 * 0.5
+    for c in range(6):
+        mine = np.flatnonzero(res["conn"] == c)
+        assert len(mine) > 1
+        # one request at a time on a connection, the next sent on the answer
+        assert np.all(res["sent"][mine[1:]] >= res["done"][mine[:-1]])
+        assert np.all(res["sent"][mine[1:]] - res["done"][mine[:-1]] < 0.05)
+    # nothing sent after the window's time; blocks cycle through the pool
+    assert res["sent"].max() < float(res["t0"]) + 0.6
+    assert np.array_equal(res["block"][np.argsort(res["sent"])], np.arange(n) % 8)

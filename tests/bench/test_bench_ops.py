@@ -60,8 +60,45 @@ def test_readers_give_nothing_without_a_trace():
     for name in ("fit.kernel_roofline", "fit_mfu", "fit.device_idle",
                  "search.topk_roofline", "search_mfu", "search.device_idle",
                  "http.write_p99_ms", "http.queue_p99_ms", "http.batch_fill",
-                 "http.device_span_ms", "http.device_idle"):
+                 "http.device_span_ms", "http.device_idle", "pool.dispatch_spread",
+                 "pool.device_idle", "pool.queue_p99_ms", "pool.device_span_ms",
+                 "pool.gc_share"):
         assert _metric(name).read(Bare()) is None, name
+
+
+@pytest.mark.parametrize("blocks, spread", [
+    ([40, 40, 40, 40], 1.0),
+    ([70, 30, 30, 30], 70 / 40),
+    ([0, 0, 0, 8], 4.0),
+    ([5], 1.0),
+    ([0, 0, 0, 0], None),
+])
+def test_pool_dispatch_spread_on_hand_made_counters(blocks, spread):
+    class Run:
+        counters = {"n_dispatched": blocks}
+
+    got = _metric("pool.dispatch_spread").read(Run())
+    assert got == (None if spread is None else pytest.approx(spread))
+
+
+@pytest.mark.parametrize("gc_spans, share", [
+    ([(0.10, 0.05), (0.50, 0.02)], 7.0),
+    ([(-0.05, 0.10), (0.95, 0.10), (1.20, 0.10)], 10.0),  # clipped to the window
+    ([(1.20, 0.10)], 0.0),  # collections, none inside the window
+    ([], None),  # no hook, nothing to read
+])
+def test_pool_gc_share_on_hand_made_spans(gc_spans, share):
+    from bench.trace_reduce import Event, Reduction
+
+    events = [Event("/host:CPU", "python", "bench.window", 0.0, 1.0),
+              Event("/host:CPU", "python", "hdc.batcher.assemble", 0.1, 0.3)]
+    events += [Event("/host:CPU", "python", "python.gc", t, d) for t, d in gc_spans]
+
+    class Run:
+        reduction = Reduction(events)
+
+    got = _metric("pool.gc_share").read(Run())
+    assert got == (None if share is None else pytest.approx(share))
 
 
 def test_fit_epoch_steps_come_from_the_traffic():

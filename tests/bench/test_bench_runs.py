@@ -6,7 +6,7 @@ import json
 
 import pytest
 
-from bench_tiny import ROOT, TINY, root_with_http
+from bench_tiny import ROOT, TINY, root_with_unlisted
 
 
 @pytest.fixture(scope="module")
@@ -21,7 +21,7 @@ def test_sound_run(run_tiny, bench, workload, trace, tmp_path):
 
     root = ROOT
     if workload not in {w["name"] for w in bench["workloads"]}:
-        root = root_with_http(tmp_path)
+        root = root_with_unlisted(tmp_path)
         bench = harness.load_benchmark(root)
     out = run_tiny(workload, trace=trace, root=root)
     assert list(out)[:5] == ["correct", "attempted", "failed", "metrics", "device"]

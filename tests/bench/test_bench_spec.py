@@ -4,10 +4,11 @@ name; a new cell, traffic mix and metric are added by adding files."""
 import json
 import re
 import shutil
+from pathlib import Path
 
 import pytest
 
-from bench_tiny import ROOT, TINY, with_http
+from bench_tiny import ROOT, TINY, load_tiny, with_unlisted
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -88,6 +89,10 @@ def test_metrics(bench):
             assert m["moves"] in {r["name"] for r in reported}
     names = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
     assert len(names) == len(set(names))
+    unlisted = with_unlisted(bench)
+    for key in ("workloads", "end_to_end", "per_layer"):
+        names = [m["name"] for m in unlisted[key]]
+        assert len(names) == len(set(names)), key
     for w in bench["workloads"]:
         reported = harness.e2e_metrics(bench, w)
         assert "setup_s" in {r["name"] for r in reported} and len(reported) >= 2
@@ -110,18 +115,32 @@ def test_peaks_table_refuses_an_unknown_device():
         harness.load_peaks("TPU v9 imaginary")
 
 
-def test_a_new_cell_is_only_new_files(tmp_path, monkeypatch):
-    """A low-rate traffic mix, its cell and a new per-layer metric are
-    added to a copy of the benchmark as files and entries alone, and the
-    harness runs the new cell and reports the new metric."""
-    from bench import harness
+def _sources(root):
+    return {p.relative_to(root): p.read_bytes()
+            for d in ("bench", "tests/bench") for p in sorted((root / d).rglob("*.py"))}
 
-    shutil.copytree(ROOT / "bench", tmp_path / "bench")
+
+def test_a_new_cell_is_only_new_files(tmp_path, monkeypatch):
+    """A low-rate traffic mix, its cell, its tiny size and a new per-layer
+    metric are added to a copy of the benchmark and its tests as files and
+    entries alone: the copy's tiny sizes find the new cell, the harness
+    runs it and reports the new metric, its control comes out not
+    correct, and no `.py` file of `bench/` or `tests/bench/` changed."""
+    from bench import control, harness
+
+    skip = shutil.ignore_patterns("__pycache__")
+    shutil.copytree(ROOT / "bench", tmp_path / "bench", ignore=skip)
+    shutil.copytree(ROOT / "tests/bench", tmp_path / "tests/bench", ignore=skip)
     (tmp_path / "src").symlink_to(ROOT / "src")
-    bench = with_http(json.loads((ROOT / "BENCHMARK.json").read_text()))
+    before = _sources(tmp_path)
+
+    bench = with_unlisted(json.loads((ROOT / "BENCHMARK.json").read_text()))
     traffic = json.loads((ROOT / "bench/traffic/http_poisson_1img.json").read_text())
     traffic["rate_per_s"] = traffic["rate_per_s"] / 20
     (tmp_path / "bench/traffic/http_poisson_1img_low.json").write_text(json.dumps(traffic))
+    tiny = TINY["predict_dyn_http_poisson"]
+    (tmp_path / "tests/bench/tiny/predict_dyn_http_lowrate.json").write_text(json.dumps(
+        {"config": tiny["config"], "traffic": {**tiny["traffic"], "rate_per_s": 20}}))
     bench["workloads"].append({"name": "predict_dyn_http_lowrate",
                                "config": "uhd_dynamic_mnist_d8192",
                                "traffic": "http_poisson_1img_low", "chips": 1,
@@ -137,13 +156,20 @@ def test_a_new_cell_is_only_new_files(tmp_path, monkeypatch):
         "from bench.readers import span_ms\n\n\ndef read(run):\n"
         "    return span_ms(run, 'assembly', 99)\n")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    sizes = load_tiny(tmp_path / "tests/bench/tiny")
+    assert {w["name"] for w in bench["workloads"]} <= set(sizes)
     monkeypatch.setattr(harness, "enable_compile_cache", lambda root: "off")
-    tiny = TINY["predict_dyn_http_poisson"]
-    low = {**tiny, "traffic": {**tiny["traffic"], "rate_per_s": 20}}
-    out = harness.run_cell(workload="predict_dyn_http_lowrate", seed=5, seconds=1.0,
-                           trace=True, require_tpu=False, root=tmp_path,
-                           bench=tmp_path / "bench",
-                           overrides=low)
+    kw = dict(seed=5, seconds=1.0, require_tpu=False, root=tmp_path,
+              bench=tmp_path / "bench", overrides=sizes["predict_dyn_http_lowrate"])
+    out = harness.run_cell(workload="predict_dyn_http_lowrate", trace=True, **kw)
     assert out["correct"], out["checks"]
     assert "http.assembly_p99_ms" in out["metrics"]
     assert "http.write_p99_ms" not in out["metrics"]  # listed for its own cell only
+    with control.swapped("predict_dyn_http_lowrate", 5, root=tmp_path,
+                         bench=tmp_path / "bench", overrides=kw["overrides"]):
+        low = harness.run_cell(workload="predict_dyn_http_lowrate", trace=False, **kw)
+    assert not low["correct"]
+    after = _sources(tmp_path)
+    assert {k: after[k] for k in before} == before  # no existing file edited
+    assert set(after) - set(before) == {Path("bench/metrics/http.assembly_p99_ms.py")}
