@@ -259,6 +259,59 @@ def bundle_by_class(hvs: jax.Array, labels: jax.Array, n_classes: int) -> jax.Ar
     )
 
 
+def retrain_update(
+    hvs: jax.Array, labels: jax.Array, predicted: jax.Array, n_classes: int,
+    max_abs: int,
+) -> jax.Array:
+    """The retraining fold of one batch (DESIGN.md §15): (C, D) int32
+
+        sum_i (e_{y_i} - e_{yhat_i}) hv_i^T
+
+    — each mispredicted image added to its true class and subtracted
+    from the predicted one.  A correct row's signs cancel
+    (e_y - e_y = 0), so no separate mispredict mask is needed.
+
+    Exact on the MXU: the (B, C) signs are int8 in {-1, 0, 1}, and each
+    hypervector, whose entries lie in [-max_abs, max_abs], is split into
+    base-128 int8 digits (the low ones in [0, 127], the top one signed),
+    one int8 x int8 -> int32 contraction per digit.  Two digits cover
+    |hv| < 2^14; int32 wrap-around is the accumulator's own.
+    """
+    cls = jnp.arange(n_classes, dtype=jnp.int32)[None, :]
+    signs = ((labels.astype(jnp.int32)[:, None] == cls).astype(jnp.int8)
+             - (predicted.astype(jnp.int32)[:, None] == cls).astype(jnp.int8))
+    n_digits = max(1, -(-int(max_abs).bit_length() // 7))
+    rest = hvs.astype(jnp.int32)
+    out = jnp.zeros((n_classes, hvs.shape[-1]), jnp.int32)
+    for k in range(n_digits):
+        digit = rest if k == n_digits - 1 else rest & 127
+        part = jnp.einsum("bc,bd->cd", signs, digit.astype(jnp.int8),
+                          preferred_element_type=jnp.int32)
+        out = out + (part << (7 * k))
+        rest = rest >> 7
+    return out
+
+
+@jax.jit
+def _label_range(label_batches: tuple[jax.Array, ...]) -> jax.Array:
+    flat = jnp.concatenate([jnp.ravel(y).astype(jnp.int32) for y in label_batches])
+    return jnp.stack([flat.min(), flat.max()])
+
+
+def validate_label_batches(label_batches, n_classes: int) -> None:
+    """`validate_labels` over many batches with one device read: the
+    smallest and largest label of them all, reduced in one jitted call.
+    Only when that range is out of bounds are the batches read one by
+    one, to name the bad values."""
+    label_batches = tuple(jnp.asarray(y) for y in label_batches)
+    if not any(y.size for y in label_batches):
+        return
+    lo, hi = (int(v) for v in np.asarray(_label_range(label_batches)))
+    if lo < 0 or hi >= n_classes:
+        for labels in label_batches:
+            validate_labels(labels, n_classes)
+
+
 def validate_labels(labels, n_classes: int) -> None:
     """Host-path label validation: raise loudly on labels outside
     ``[0, n_classes)`` instead of silently dropping them (the jitted

@@ -256,6 +256,45 @@ class HDCModel:
                 )
         return self.replace(class_sums=sums, n_seen=n_seen)
 
+    def retrain_batches(
+        self, batches: Iterable[tuple[Any, Any]], *, epochs: int = 1
+    ) -> tuple["HDCModel", jax.Array]:
+        """Mispredict-driven retraining epochs from this model's class
+        sums (DESIGN.md §15).  Per batch, in order: encode each image
+        once, label it as `predict_packed` would serve (packed Hamming
+        against this step's starting sums, lowest class on ties), then
+        add every mispredicted image to its true class and subtract it
+        from the predicted one, exactly in int32.  Epoch e + 1 starts
+        from epoch e's sums; ``n_seen`` is unchanged.
+
+        Returns the retrained model and the (epochs * steps,) int32
+        mispredict count of every step, on the device: nothing is read
+        back per step.  ``batches`` is iterated once per epoch (an
+        iterator is listed first); all labels are range-checked once,
+        before any step.  The sums are copied once and then donated
+        between steps; this model's buffers are untouched."""
+        if epochs < 1:
+            raise ValueError(f"epochs must be >= 1, got {epochs}")
+        batches = [(jnp.asarray(x), jnp.asarray(y)) for x, y in batches]
+        encoding.validate_label_batches([y for _, y in batches], self.cfg.n_classes)
+        with span("hdc.retrain.reset"):
+            sums = jnp.copy(self.class_sums)
+        view = _stateless(self)
+        wrong = []
+        for _ in range(epochs):
+            for images, labels in batches:
+                # the step's host work: the served class words of the
+                # step's starting sums (`pack`, op by op as serving
+                # builds them) and the donated dispatch
+                with span("hdc.retrain.step"):
+                    class_words = self.replace(class_sums=sums).pack()
+                    sums, n_wrong = _retrain_step_donated(
+                        view, sums, class_words, images, labels
+                    )
+                    wrong.append(n_wrong)
+        counts = jnp.stack(wrong) if wrong else jnp.zeros((0,), jnp.int32)
+        return self.replace(class_sums=sums), counts
+
     def reset(self) -> "HDCModel":
         """Drop accumulated class state (codebooks are kept)."""
         sums, n_seen = _zeroed(self.class_sums, self.n_seen)
@@ -539,6 +578,34 @@ def _partial_fit_donated(
     model = stateless.replace(class_sums=class_sums, n_seen=n_seen)
     out = _partial_fit(model, images, labels)
     return out.class_sums, out.n_seen
+
+
+@functools.partial(jax.jit, donate_argnums=(1,))
+def _retrain_step_donated(
+    stateless: HDCModel,
+    class_sums: jax.Array,
+    class_words: jax.Array,
+    images: jax.Array,
+    labels: jax.Array,
+) -> tuple[jax.Array, jax.Array]:
+    """One retraining step (DESIGN.md §15) with the class sums donated.
+
+    The batch is encoded once through the registered backend; its
+    packed queries are scored against `class_words` (the step's
+    starting sums as `HDCModel.pack` serves them) by the backend's
+    top-1, and the same hypervectors are folded into the sums by
+    `encoding.retrain_update`.  Returns the new sums and the step's
+    mispredict count."""
+    cfg = stateless.cfg
+    hv = _encode(stateless, images)
+    q = encoding.binarize(hv).astype(jnp.int32) if cfg.binarize_query else hv
+    enc = registry.get_encoder(cfg.encoder)
+    idx, _ = enc.topk(stateless.pack_queries(q), class_words, cfg.d, 1,
+                      backend=cfg.backend)
+    predicted = idx[:, 0]
+    update = encoding.retrain_update(hv, labels, predicted, cfg.n_classes,
+                                     cfg.n_features)
+    return class_sums + update, jnp.sum(predicted != labels, dtype=jnp.int32)
 
 
 def _fit(model: HDCModel, images: jax.Array, labels: jax.Array) -> HDCModel:

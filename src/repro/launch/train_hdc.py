@@ -1,12 +1,14 @@
-"""The paper's system, end-to-end and sharded: uHD single-pass training.
+"""The paper's system, end-to-end and sharded: uHD single-pass training,
+optionally refined by mispredict-driven retraining epochs.
 
     PYTHONPATH=src python -m repro.launch.train_hdc --dataset synth_mnist \
-        --d 8192 --backend auto --compare-baseline
+        --d 8192 --backend auto --retrain-epochs 3 --compare-baseline
 
 Built on the `HDCModel` API: create -> fit_batches (streamed) ->
-evaluate -> save.  The datapath is picked by name (--backend) through
-the encoder/backend registry; "auto" resolves per platform (Pallas on
-TPU, MXU-unary matmul elsewhere).
+retrain_batches per epoch (DESIGN.md §15) -> evaluate -> save.  The
+datapath is picked by name (--backend) through the encoder/backend
+registry; "auto" resolves per platform (Pallas on TPU, MXU-unary
+matmul elsewhere).
 
 Under a mesh the image batch shards over the batch axes and the class
 bundling reduces with one psum of (C, D) — the distributed form of the
@@ -57,6 +59,13 @@ def main(argv=None) -> int:
              "hosts in this single process) and verify the stitched "
              "restore",
     )
+    ap.add_argument(
+        "--retrain-epochs", type=int, default=0,
+        help="after the single pass, N epochs of mispredict-driven "
+             "retraining (HDCModel.retrain_batches); prints the test "
+             "accuracy and the share of training images mispredicted "
+             "in each epoch",
+    )
     ap.add_argument("--compare-baseline", action="store_true")
     ap.add_argument("--baseline-iters", type=int, default=5)
     args = ap.parse_args(argv)
@@ -94,6 +103,18 @@ def main(argv=None) -> int:
     print(f"{args.encoder}  D={args.d} backend={args.backend} [{mode}]: "
           f"accuracy {acc:.4f}  "
           f"({model.n_examples} images, single pass, {time.time()-t0:.1f}s)")
+
+    if args.retrain_epochs:
+        train = list(batches())
+        n_train = len(ds.train_images)
+        for epoch in range(1, args.retrain_epochs + 1):
+            t0 = time.time()
+            model, wrong = model.retrain_batches(train)
+            n_wrong = int(wrong.sum())
+            acc = model.evaluate(ds.test_images, ds.test_labels)
+            print(f"retrain epoch {epoch}/{args.retrain_epochs}: accuracy {acc:.4f}  "
+                  f"({n_wrong} of {n_train} training images mispredicted, "
+                  f"{n_wrong / n_train:.2%}; {time.time()-t0:.1f}s)")
 
     if args.save_dir:
         if args.ckpt_shards > 1:

@@ -62,6 +62,16 @@ KERNELS = {
         lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, interpret=False),
         [((64, H), jnp.int32), ((H, 32), jnp.uint8)],
     ),
+    # the retraining benchmark's steps encode 2048 images, and the epoch's
+    # last 608, then scan the 10 class words for each
+    "encode_bundle_dynamic_B2048": (
+        lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, interpret=False),
+        [((2048, H), jnp.int32), ((H, 32), jnp.uint8)],
+    ),
+    "encode_bundle_dynamic_B608": (
+        lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, interpret=False),
+        [((608, H), jnp.int32), ((H, 32), jnp.uint8)],
+    ),
     "fit_bundle_B256": (
         lambda x, s, y: ops.fit_bundle(x, s, y, C, L, interpret=False),
         [((256, H), jnp.int32), ((H, D), jnp.int8), ((256,), jnp.int32)],
@@ -101,6 +111,10 @@ KERNELS = {
     "hamming_topk_C10_k1": (
         lambda q, c: ops.hamming_topk(q, c, D, 1, interpret=False),
         [((64, W), jnp.uint32), ((C, W), jnp.uint32)],
+    ),
+    "hamming_topk_C10_k1_B2048": (
+        lambda q, c: ops.hamming_topk(q, c, D, 1, interpret=False),
+        [((2048, W), jnp.uint32), ((C, W), jnp.uint32)],
     ),
     # a 1 GiB store: the working set must not grow with C
     "hamming_topk_C1M_k10": (
