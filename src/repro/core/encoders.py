@@ -262,7 +262,7 @@ def _uhd_dynamic_pallas(cfg, books, x_q):
     from repro.kernels import ops  # local import: kernels are optional
 
     return ops.encode_bundle_dynamic(
-        x_q, books["direction"], cfg.d, skip=cfg.sobol_skip
+        x_q, books["direction"], cfg.d, n_levels=cfg.levels, skip=cfg.sobol_skip
     )
 
 

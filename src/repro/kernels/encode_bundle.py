@@ -1,24 +1,40 @@
-"""Fused uHD encode+bundle Pallas kernel (the paper's core operation).
+"""Fused uHD encode+bundle Pallas kernels (the paper's core operation).
 
-Computes hv[b,d] = sum_h (2*[x[b,h] >= S[h,d]] - 1) without ever
+Each computes hv[b,d] = sum_h (2*[x[b,h] >= S[h,d]] - 1) without ever
 materializing the (B, H, D) level-hypervector tensor in HBM — the TPU
 analogue of the paper's multiplier-less, position-free encoding
-(contributions 1-2): the only HBM traffic is the quantized inputs and
-the (B, D) accumulator.
+(contributions 1-2).
 
-Tiling: grid (B/bt, D/dt, H/ht); the H axis is the reduction — the
-output block index_map ignores it, so the accumulator block stays
-resident in VMEM across the H sweep (initialized at h==0).  The compare
-broadcast (bt, ht, dt) lives entirely in VREG/VMEM; ht*dt is chosen so
-the working set (x tile + sobol tile + compare cube + acc) fits VMEM
-comfortably: 8*128*512*4B ≈ 2 MiB.
+`encode_bundle` (over a stored (H, D) threshold table) compares on the
+VPU: grid (B/bt, D/dt, H/ht); the H axis is the reduction — the output
+block index_map ignores it, so the accumulator block stays resident in
+VMEM across the H sweep (initialized at h==0).  The compare broadcast
+(bt, ht, dt) lives entirely in VREG/VMEM: 8*128*512*4B ≈ 2 MiB.
 
-A `generate_sobol` variant regenerates the Sobol tile *inside* the
-kernel from the (H, 32) direction matrix (Gray-code XOR), eliminating
-the (H, D) threshold table from HBM entirely — the TPU mapping of the
-paper's "dynamic generation instead of stored tables" theme.  See
-ops.encode_bundle_dynamic, registered as the "pallas" backend of the
-"uhd_dynamic" encoder.
+The thresholds take values in [0, L) for L = `levels`, so the compare
+splits by level:
+
+    [x >= S[h,d]] = sum_{l<L} [x >= l] * [S[h,d] == l]
+
+which turns the compare-and-count into 0/1 int8 contractions on the
+MXU, int32 results, exact (DESIGN.md §9).
+
+`encode_bundle_dynamic` contracts each image this way, with no table:
+count[b,d] = sum_l [x >= l] @ [S == l], hv = 2 * count - H.  Its grid
+is (D/dt, L/16, B/bt), the whole padded H axis in every block.  At the
+first step of each D tile the (Hp, dt) Sobol tile is generated in VMEM
+from the (H, 32) direction matrix (Gray-code XOR over the direction
+bits that a point index below skip + D can set: 14 at D = 8192); at
+the first batch step of each grid row its level one-hots [S == l],
+16 int8 (Hp, dt) slices, go to a VMEM scratch and stay there for the
+batch sweep, and every batch step contracts the int8 thermometer
+slices [x >= l] (bt, Hp) with them.  Neither the (H, D) table nor its
+one-hots ever touch HBM — the TPU mapping of the paper's "dynamic
+generation instead of stored tables" theme; the HBM traffic is the
+quantized inputs, the directions and the (B, D) hypervectors.  Tiles
+follow from (B, H, D, L) alone (`encode_tiles`); rows past the first
+16 levels (L > 16) write partial counts that ops.py sums.  It is the
+"pallas" backend of the "uhd_dynamic" encoder (ops.encode_bundle_dynamic).
 
 The `fit_bundle*` kernels below fuse one more stage: per-class bundling
 (training).  The D axis is outside the batch axis in their grids, so
@@ -30,12 +46,8 @@ the only HBM traffic of a training step is the quantized inputs, the
 label indicator, the encoder state (threshold table or direction
 matrix) and the (C, D) class sums (DESIGN.md §9).
 
-`fit_bundle` (over the table) contracts on the MXU, the batch first.
-Thresholds lie in [0, L) for L = `levels`, so
-
-    [x >= S[h,d]] = sum_{l<L} [x >= l] * [S[h,d] == l]
-
-and, class sums being linear in the images, the count per class is
+`fit_bundle` (over the table) uses the level split with the batch
+first: class sums being linear in the images, the count per class is
 sum_l A_l @ [S == l] with A_l = oh^T [x >= l] (Cp, Hp) the class-c
 images at or above level l in each feature.  Its grid is (L/16, D/dt,
 B/bt): the first D tile's batch sweep folds the batch into A (a VMEM
@@ -63,9 +75,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.hamming_packed import round_up
 
-# levels per grid row of `fit_bundle`, unrolled in its body: the code and
-# the VMEM scratch stay the size of the paper's 16 levels at any level count
-FIT_LEVEL_CHUNK = 16
+# levels per grid row of the MXU kernels, unrolled in their bodies: the
+# code and the VMEM scratches stay the size of the paper's 16 levels at
+# any level count
+LEVEL_CHUNK = 16
 
 def _sobol_tile(dirs, first, block_d: int, shift: int, n_bits: int):
     """In-kernel (ht, dt) quantized Sobol tile for points [first,
@@ -141,78 +154,134 @@ def encode_bundle_pallas(
     )(x_q.astype(jnp.int32), sobol_q.astype(jnp.int32))
 
 
-def _encode_bundle_dyn_kernel(
-    x_ref, dir_ref, o_ref, *, ht: int, block_d: int, shift: int, skip: int, n_bits: int
-):
-    """Sobol-free variant: thresholds are generated in VMEM from the
-    direction matrix (dir_ref: (ht, n_bits) uint32) via Gray-code XOR.
-    `shift` right-shifts raw 32-bit Sobol integers to quantized levels
-    (0 when the direction numbers are pre-quantized).  `skip` offsets
-    the point index so the generated sequence matches a table built
-    with the same ``sobol_skip`` bit-for-bit.
+def encode_tiles(b: int, h: int, d: int, levels: int) -> tuple[int, int]:
+    """(bt, dt) of `encode_bundle_dynamic_pallas` for B images of H
+    features over D dimensions at `levels` threshold values.
+
+    The whole padded feature axis rides in every block.  dt is the
+    widest of 512, 256, 128 lanes whose VMEM-resident thresholds, an
+    int32 (Hp, dt) tile and its int8 one-hot slice for each level of a
+    grid row, stay within 9 MiB (less only for a narrower D).  The
+    batch splits into the fewest tiles of at most 256 rows and 2**18
+    elements, a multiple of 32 (the int8 sublane tile of the
+    thermometer), so a ragged last step pads little.  Up to 4096
+    features fit a v5e's default scoped VMEM.
     """
-    k = pl.program_id(2)
-    j = pl.program_id(1)
+    hp, chunk = round_up(h, 128), min(levels, LEVEL_CHUNK)
+    dt = 512
+    while dt > 128 and (chunk + 4) * hp * dt > 9 << 20:
+        dt //= 2
+    cap = min(256, max(32, (1 << 18) // hp // 32 * 32))
+    bt = round_up(pl.cdiv(b, pl.cdiv(b, cap)), 32)
+    return bt, min(dt, round_up(d, 128))
 
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
 
-    # Generate the (ht, dt) quantized Sobol tile for points
-    # [skip + j*dt, skip + (j+1)*dt) — `skip` drops the leading points,
-    # point 0 (all zeros) being degenerate, exactly like the table path.
-    s = _sobol_tile(dir_ref[...], j * block_d + skip, block_d, shift, n_bits)
+def _encode_bundle_dyn_kernel(
+    x_ref, dir_ref, o_ref, s_ref, oh_ref, *, h: int, shift: int, skip: int
+):
+    """x (bt, hp) i32, dir (hp, n_bits) u32 -> o (1, bt, dt) i32.
 
-    ge = x_ref[...][:, :, None] >= s[None, :, :]
-    o_ref[...] += 2 * ge.sum(axis=1, dtype=jnp.int32) - ht
+    o[b, d] = 2 * #{h : first <= S[h,d] < first + chunk, x[b,h] >= S[h,d]}
+    over the levels [first, first + chunk) of this grid row, less `h`
+    in the first row, so the rows sum to the hypervector.  At the first
+    step of each D tile the (hp, dt) Sobol tile for points
+    [skip + j*dt, skip + (j+1)*dt) is generated from the direction
+    matrix (Gray-code XOR, then `shift` to the levels) into s_ref; at
+    the first batch step of each level row its level one-hots [S == l]
+    go to oh_ref (chunk, hp, dt) int8 and stay for the batch sweep.
+    Every batch step contracts the thermometer [x >= l] with them on
+    the MXU (int32 result, exact).  Padded features (x = -1, zero
+    directions so S = 0) match no level.
+    """
+    j, g, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    chunk, dt = oh_ref.shape[0], oh_ref.shape[2]
+    first = g * chunk
+
+    def by_rows(body):  # 128 features at a time: temporaries stay small
+        def step(r, carry):
+            body(pl.ds(pl.multiple_of(r * 128, 128), 128))
+            return carry
+
+        jax.lax.fori_loop(0, s_ref.shape[0] // 128, step, 0)
+
+    @pl.when((g == 0) & (i == 0))
+    def _thresholds():
+        def gen(rows):
+            s_ref[rows, :] = _sobol_tile(
+                dir_ref[rows, :], j * dt + skip, dt, shift, dir_ref.shape[1]
+            )
+
+        by_rows(gen)
+
+    @pl.when(i == 0)
+    def _one_hots():
+        def split(rows):
+            s = s_ref[rows, :]
+            for t in range(chunk):
+                oh_ref[t, rows, :] = (s == first + t).astype(jnp.int8)
+
+        by_rows(split)
+
+    x = x_ref[...]
+    acc = jnp.zeros(o_ref.shape[1:], jnp.int32)
+    for t in range(chunk):
+        u = (x >= first + t).astype(jnp.int8)
+        acc += jnp.dot(u, oh_ref[t], preferred_element_type=jnp.int32)
+    o_ref[0] = 2 * acc - jnp.where(g == 0, h, 0)
 
 
 def encode_bundle_dynamic_pallas(
     x_q: jax.Array,
     direction: jax.Array,
     d: int,
+    levels: int,
     *,
+    h: int,
     shift: int = 0,
     skip: int = 1,
-    block_b: int = 8,
-    block_h: int = 128,
-    block_d: int = 512,
+    block_b: int,
+    block_d: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """Fused encode+bundle with in-kernel Sobol generation.
+    """Fused encode+bundle with in-kernel Sobol generation, on the MXU.
 
-    x_q: (B, H) int32; direction: (H, n_bits) uint direction integers
-    (raw 32-bit with ``shift = 32 - M``, or M-bit pre-quantized with
-    ``shift = 0``); `d` = hypervector dimensionality (number of Sobol
-    points generated), `skip` = leading points dropped (``sobol_skip``).
-    HBM traffic drops from O(H*D) (threshold table) to O(H*n_bits).
+    x_q: (B, Hp) int32, padded features -1; direction: (Hp, 32) uint
+    direction integers, zero rows for padded features (raw 32-bit with
+    ``shift = 32 - M``, or M-bit pre-quantized with ``shift = 0``), the
+    generated thresholds lying in [0, levels); `d` = hypervector
+    dimensionality (number of Sobol points generated), `skip` =
+    leading points dropped (``sobol_skip``), `h` = real features.
+    Requires Hp % 128 == 0 and B/D divisible by their blocks (ops.py
+    pads and takes the blocks from `encode_tiles`).  HBM traffic is the
+    inputs, O(Hp * 32) of directions and the (B, D) int32 hypervectors:
+    neither the threshold table nor its one-hots leave VMEM.
     """
-    b, h = x_q.shape
-    h2, n_bits = direction.shape
-    assert h == h2
-    assert b % block_b == 0 and h % block_h == 0 and d % block_d == 0
+    b, hp = x_q.shape
+    assert direction.shape[0] == hp
+    assert b % block_b == 0 and hp % 128 == 0 and d % block_d == 0
+    chunk = min(levels, LEVEL_CHUNK)
+    assert levels % chunk == 0
+    # gray(p) < 2**n for p < 2**n: higher direction bits never enter
+    n_bits = min(direction.shape[1], (skip + d - 1).bit_length())
 
-    grid = (b // block_b, d // block_d, h // block_h)
-    return pl.pallas_call(
-        functools.partial(
-            _encode_bundle_dyn_kernel,
-            ht=block_h,
-            block_d=block_d,
-            shift=shift,
-            skip=skip,
-            n_bits=n_bits,
-        ),
-        grid=grid,
+    out = pl.pallas_call(
+        functools.partial(_encode_bundle_dyn_kernel, h=h, shift=shift, skip=skip),
+        grid=(d // block_d, levels // chunk, b // block_b),
         in_specs=[
-            pl.BlockSpec((block_b, block_h), lambda i, j, k: (i, k)),
-            pl.BlockSpec((block_h, n_bits), lambda i, j, k: (k, 0)),
+            pl.BlockSpec((block_b, hp), lambda j, g, i: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.VMEM),  # whole, one buffer
         ],
-        out_specs=pl.BlockSpec((block_b, block_d), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, d), jnp.int32),
+        out_specs=pl.BlockSpec((1, block_b, block_d), lambda j, g, i: (g, i, j)),
+        out_shape=jax.ShapeDtypeStruct((levels // chunk, b, d), jnp.int32),
+        scratch_shapes=[
+            pltpu.VMEM((hp, block_d), jnp.int32),
+            pltpu.VMEM((chunk, hp, block_d), jnp.int8),
+        ],
         interpret=interpret,
         name="encode_bundle_dynamic",
         metadata={"hdc_kernel": "encode_bundle_dynamic"},
-    )(x_q.astype(jnp.int32), direction.astype(jnp.uint32))
+    )(x_q.astype(jnp.int32), direction[:, :n_bits].astype(jnp.uint32))
+    return out.sum(axis=0)
 
 
 def fit_tiles(b: int, h: int, d: int) -> tuple[int, int]:
@@ -304,7 +373,7 @@ def fit_bundle_pallas(
     assert h == h2 and onehot_t.shape[1] == b
     assert b % block_b == 0 and h % 128 == 0 and d % block_d == 0
     assert jnp.iinfo(sobol_q.dtype).max >= levels
-    chunk = min(levels, FIT_LEVEL_CHUNK)
+    chunk = min(levels, LEVEL_CHUNK)
     assert levels % chunk == 0
     nb = b // block_b
 

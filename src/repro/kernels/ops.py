@@ -26,6 +26,7 @@ from repro.kernels.bundle_binarize import bundle_binarize_pallas
 from repro.kernels.encode_bundle import (
     encode_bundle_dynamic_pallas,
     encode_bundle_pallas,
+    encode_tiles,
     fit_bundle_dynamic_pallas,
     fit_bundle_pallas,
     fit_tiles,
@@ -37,15 +38,6 @@ from repro.kernels.hamming_topk import hamming_topk_pallas
 
 def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def _pick_block(n: int, target: int) -> int:
-    """Largest divisor of n that is <= target (n is padded upstream)."""
-    best = 1
-    for cand in range(1, min(n, target) + 1):
-        if n % cand == 0:
-            best = cand
-    return best
 
 
 def encode_bundle(
@@ -84,10 +76,8 @@ def encode_bundle_dynamic(
     d: int,
     *,
     levels: int | None = None,
+    n_levels: int | None = None,
     skip: int = 1,
-    block_b: int = 8,
-    block_h: int = 128,
-    block_d: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused encode+bundle with in-kernel Sobol generation (no HBM table).
@@ -97,34 +87,33 @@ def encode_bundle_dynamic(
     generated points are right-shifted to [0, levels) in-kernel; with
     ``levels=None`` they are already M-bit quantized
     (`sobol.quantized_direction_matrix`) and used as-is — exact either
-    way, since right-shift distributes over XOR.  `skip` must match the
-    table's ``sobol_skip``; then the result equals
-    ``encode_bundle(x_q, quantized_sobol_table)`` bit-for-bit.
+    way, since right-shift distributes over XOR.  `n_levels` is the
+    number of threshold values, the configuration's level count: the
+    kernel contracts one level per slice (DESIGN.md §9).  It defaults
+    to `levels` and is required with pre-quantized directions.  `skip`
+    must match the table's ``sobol_skip``; then the result equals
+    ``encode_bundle(x_q, quantized_sobol_table)`` bit-for-bit.  Tiles
+    follow from the shapes (`encode_tiles`).
     """
     if interpret is None:
         interpret = _interpret_default()
+    n_levels = levels if n_levels is None else n_levels
+    if n_levels is None:
+        raise ValueError("pre-quantized directions need n_levels, the level count")
     b, h = x_q.shape
     shift = 0 if levels is None else 32 - (int(levels).bit_length() - 1)
-    bp, hp, dp = _round_up(b, block_b), _round_up(h, block_h), _round_up(d, block_d)
+    block_b, block_d = encode_tiles(b, h, d, n_levels)
+    bp, hp, dp = _round_up(b, block_b), _round_up(h, 128), _round_up(d, block_d)
+    # Padded features get intensity -1 and zero direction vectors, so
+    # every generated threshold there is 0 and -1 >= l holds for no
+    # level: they never count.  Padded rows and columns are sliced off.
     xp = jnp.pad(x_q.astype(jnp.int32), ((0, bp - b), (0, hp - h)), constant_values=-1)
-    # Padded features get zero direction vectors -> every generated
-    # threshold is exactly 0 for every `levels`/`shift` setting, and the
-    # pad intensity -1 never satisfies -1 >= 0 (real x_q can be 0, but
-    # real rows never meet padded thresholds) -> each padded feature
-    # contributes exactly -1 per dim, corrected below.
     dirp = jnp.pad(direction.astype(jnp.uint32), ((0, hp - h), (0, 0)))
     out = encode_bundle_dynamic_pallas(
-        xp,
-        dirp,
-        dp,
-        shift=shift,
-        skip=skip,
-        block_b=block_b,
-        block_h=block_h,
-        block_d=block_d,
-        interpret=interpret,
+        xp, dirp, dp, n_levels, h=h, shift=shift, skip=skip,
+        block_b=block_b, block_d=block_d, interpret=interpret,
     )
-    return out[:b, :d] + (hp - h)
+    return out[:b, :d]
 
 
 def _padded_class_onehot(labels: jax.Array, c_pad: int, b_pad: int) -> jax.Array:

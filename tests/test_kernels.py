@@ -46,16 +46,24 @@ def test_encode_unary_mxu_kernel(b, h, d):
         (8, 112, 1024, 16, 7),   # nonzero sobol_skip must match the table
         (3, 113, 640, 2, 1),     # H % tile == 1, D % tile != 0, 1-bit levels
         (5, 100, 576, 256, 3),   # 8-bit quantization + skip
+        (1, 50, 512, 16, 1),     # one image in a 32-row batch tile
+        (37, 60, 640, 16, 1),    # B not a multiple of the batch tile
+        (300, 100, 1280, 16, 1),  # two batch tiles x three D tiles
+        (300, 40, 1100, 2, 1000),  # both, 1-bit levels, a skip past 2**9
+        (40, 60, 640, 256, 5),   # sixteen level rows of 16
+        (260, 30, 700, 256, 2),  # level rows x batch tiles x D tiles
     ],
 )
 def test_encode_bundle_dynamic_kernel(b, h, d, levels, skip):
     """In-kernel Sobol generation == table-based encode, bit-exact,
-    including nonzero skip and padded H (the all-zero padded direction
-    rows must contribute exactly -1 per dim for every `levels`)."""
+    including nonzero skip and padded H (the padded features, -1
+    against all-zero direction rows, must match no level), across the
+    kernel's batch, D and level-row tiles."""
     x = jnp.asarray(RNG.integers(0, levels + 1, (b, h)), jnp.int32)
     # pin the x_q == 0 edge: a whole real row at the minimum intensity
-    # still compares correctly against padded threshold-0 rows
-    x = x.at[0].set(0)
+    # still compares correctly against padded threshold-0 rows; and a
+    # row at the maximum level L, above every threshold
+    x = x.at[0].set(0).at[-1].set(levels)
     s = jnp.asarray(sobol.sobol_table_for_features(h, d, levels, skip=skip), jnp.int32)
     want = ref.encode_bundle(x, s)
     dirs = jnp.asarray(sobol.direction_matrix(h).astype(np.uint32))
@@ -64,8 +72,48 @@ def test_encode_bundle_dynamic_kernel(b, h, d, levels, skip):
     # pre-quantized direction numbers (the uhd_dynamic codebook): exact
     # because right-shift distributes over XOR
     qdirs = jnp.asarray(sobol.quantized_direction_matrix(h, levels))
-    got_q = ops.encode_bundle_dynamic(x, qdirs, d, skip=skip)
+    got_q = ops.encode_bundle_dynamic(x, qdirs, d, n_levels=levels, skip=skip)
     np.testing.assert_array_equal(np.asarray(got_q), np.asarray(want))
+
+
+def test_encode_bundle_dynamic_needs_the_level_count():
+    """Pre-quantized directions do not say how many levels the kernel
+    contracts: the caller must."""
+    qdirs = jnp.asarray(sobol.quantized_direction_matrix(20, 16))
+    x = jnp.zeros((2, 20), jnp.int32)
+    with pytest.raises(ValueError, match="n_levels"):
+        ops.encode_bundle_dynamic(x, qdirs, 256)
+
+
+def _reader_pattern(metric):
+    """The `OP_PATTERN` literal of a benchmark reader, read from its file."""
+    import ast
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "bench" / "metrics" / f"{metric}.py"
+    for node in ast.parse(path.read_text()).body:
+        if isinstance(node, ast.Assign) and any(
+                getattr(t, "id", None) == "OP_PATTERN" for t in node.targets):
+            return ast.literal_eval(node.value)
+    raise AssertionError(f"{path} defines no OP_PATTERN")
+
+
+@pytest.mark.parametrize("metric", ["search.encode_ms", "retrain.encode_roofline"])
+def test_encode_kernel_carries_the_name_the_readers_key_on(metric):
+    """Every Pallas call that `ops.encode_bundle_dynamic` makes carries
+    metadata that the reader of the encode finds in the custom call's
+    text, where the kernel holds it as compact JSON."""
+    import json
+    import re
+
+    jaxpr = jax.make_jaxpr(lambda x, d: ops.encode_bundle_dynamic(x, d, 640, n_levels=32))(
+        jnp.zeros((40, 50), jnp.int32), jnp.zeros((50, 32), jnp.uint8))
+    calls = [e.params["metadata"] for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert calls
+    pattern = _reader_pattern(metric)
+    for metadata in calls:
+        text = f"kernel_metadata={json.dumps(dict(metadata), separators=(',', ':'))}"
+        assert re.search(pattern, text), text
 
 
 def test_sobol_tile_ref_matches_generator():

@@ -59,18 +59,33 @@ KERNELS = {
         [((64, H), jnp.int32), ((H, D), jnp.int8)],
     ),
     "encode_bundle_dynamic_B64": (
-        lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, interpret=False),
+        lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, n_levels=L, interpret=False),
         [((64, H), jnp.int32), ((H, 32), jnp.uint8)],
     ),
     # the retraining benchmark's steps encode 2048 images, and the epoch's
     # last 608, then scan the 10 class words for each
     "encode_bundle_dynamic_B2048": (
-        lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, interpret=False),
+        lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, n_levels=L, interpret=False),
         [((2048, H), jnp.int32), ((H, 32), jnp.uint8)],
     ),
     "encode_bundle_dynamic_B608": (
-        lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, interpret=False),
+        lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, n_levels=L, interpret=False),
         [((608, H), jnp.int32), ((H, 32), jnp.uint8)],
+    ),
+    # the paper's other width, and 3072 features: the tiles shrink so
+    # the level one-hots and the whole feature axis fit VMEM; 256
+    # levels: sixteen grid rows
+    "encode_bundle_dynamic_H1024": (
+        lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, n_levels=L, interpret=False),
+        [((2048, 1024), jnp.int32), ((1024, 32), jnp.uint8)],
+    ),
+    "encode_bundle_dynamic_H3072": (
+        lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, n_levels=L, interpret=False),
+        [((2048, 3072), jnp.int32), ((3072, 32), jnp.uint8)],
+    ),
+    "encode_bundle_dynamic_B2048_L256": (
+        lambda x, dr: ops.encode_bundle_dynamic(x, dr, D, n_levels=256, interpret=False),
+        [((2048, H), jnp.int32), ((H, 32), jnp.uint8)],
     ),
     "fit_bundle_B256": (
         lambda x, s, y: ops.fit_bundle(x, s, y, C, L, interpret=False),
